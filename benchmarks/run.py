@@ -74,6 +74,9 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="write combined results JSON here")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_accuracy,
         bench_error_analysis,

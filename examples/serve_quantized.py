@@ -83,10 +83,11 @@ def main(smoke: bool = False):
         print(f"  [{mode:>10}] {p!r} -> {txt!r}")
     th = engine.throughput()
     total_new = sum(len(r.out) for r in requests)
+    dev = jax.devices()[0]
     print(f" served {len(requests)} requests, {total_new} tokens in {dt:.1f}s: "
           f"decode {th['decode_tok_s']:.1f} tok/s, prefill {th['prefill_tok_s']:.1f} tok/s, "
           f"mean occupancy {th['mean_batch_occupancy']:.2f}/{engine.batch} slots "
-          f"(1 CPU core, oracle numerics)")
+          f"(on {dev.platform} {dev.device_kind})")
     # which kernel schedule each quantized linear routed to, per trace:
     # decode steps (M=slots<=8) must hit the decode-shaped schedule, the
     # prompt prefill (M=prompt length) the prefill one

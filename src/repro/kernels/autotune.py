@@ -53,6 +53,8 @@ from typing import Callable, Optional
 
 import jax
 
+from repro.kernels.contracts import LANE, SUBLANE
+
 __all__ = [
     "TuneCache",
     "autotune_blocks",
@@ -72,6 +74,7 @@ DECODE_M_MAX = 8
 
 _BN_CANDIDATES = (512, 256, 128)
 _BK_CANDIDATES = (1024, 512, 256, 128)
+_DECODE_KINDS = ("dual_decode", "dual_decode_fused")
 
 
 def regime(m: int) -> str:
@@ -96,31 +99,37 @@ def heuristic_blocks(
 ) -> Optional[tuple[int, int, int]]:
     """Deterministic block triple for a tileable shape, else ``None``.
 
-    Validity contract (matches the kernel asserts):
-      * ``k % block_k == 0`` and ``block_k % group == 0``
-      * ``n % block_n == 0`` with ``block_n`` MXU-lane aligned (128x)
-      * dual kernels additionally need ``rank % rgroup == 0`` upstream —
-        checked by the dispatch layer, not here.
+    Validity contract (matches ``kernels/contracts.py``, so every triple
+    returned here is one the TPU compiler accepts):
+      * ``k % block_k == 0`` and ``block_k % group == 0``, with ``block_k``
+        a multiple of 128 or the whole K (the (8, 128) block rule)
+      * ``n % block_n == 0`` with ``block_n`` lane aligned (128x)
+      * dual kernels slice each scale group at a dynamic lane offset, so
+        their ``group`` must be a multiple of 128 (the lane-aligned store
+        rule); ``rank % rgroup == 0`` is checked by the dispatch layer.
     """
     if k <= 0 or n <= 0 or m <= 0:
         return None
     if k % group != 0 or group % 2 != 0:
         return None
+    if kind.startswith("dual") and group % LANE != 0:
+        return None
     bn = next((c for c in _BN_CANDIDATES if n % c == 0), None)
     if bn is None:
         return None
-    if kind in ("dual_decode", "dual_decode_fused"):
+    if kind in _DECODE_KINDS:
         # whole-K schedule: block_k is unused by the gemv grid but recorded
         # as K so cache entries stay self-describing. For the fused kind the
         # caller passes n = gcd over segment widths, so bn | every segment.
         return (DECODE_M_MAX, bn, k)
-    bk = next((c for c in _BK_CANDIDATES if k % c == 0 and c % group == 0), None)
-    if bk is None:
-        bk = group if k % group == 0 else None
-    if bk is None:
-        return None
     bm = min(128, _round_up_pow2(m))
-    return (bm, bn, bk)
+    return (bm, bn, _block_ks(k, group)[0])
+
+
+def _block_ks(k: int, group: int) -> list[int]:
+    """Legal K blocks, largest first; the whole K when no candidate tiles
+    it (a full-extent block always satisfies the (8, 128) rule)."""
+    return [c for c in _BK_CANDIDATES if k % c == 0 and c % group == 0] or [k]
 
 
 def candidate_blocks(
@@ -130,14 +139,11 @@ def candidate_blocks(
     base = heuristic_blocks(kind, m, n, k, group, rank)
     if base is None:
         return []
-    if kind in ("dual_decode", "dual_decode_fused"):
+    if kind in _DECODE_KINDS:
         return [(DECODE_M_MAX, bn, k) for bn in _BN_CANDIDATES if n % bn == 0]
     bms = sorted({min(128, _round_up_pow2(m)), 128} | ({64} if m >= 64 else set()))
     bns = [c for c in _BN_CANDIDATES if n % c == 0]
-    bks = [c for c in _BK_CANDIDATES if k % c == 0 and c % group == 0]
-    if not bks and k % group == 0:
-        bks = [group]
-    return [(bm, bn, bk) for bm in bms for bn in bns for bk in bks]
+    return [(bm, bn, bk) for bm in bms for bn in bns for bk in _block_ks(k, group)]
 
 
 class TuneCache:
@@ -233,13 +239,19 @@ def default_cache() -> TuneCache:
 def blocks_valid(
     kind: str, blocks: tuple[int, int, int], n: int, k: int, group: int
 ) -> bool:
-    """Do these blocks satisfy the kernel tiling asserts for (n, k, group)?"""
+    """Do these blocks satisfy the kernel tiling contract for (n, k, group),
+    the TPU's (8, 128) block rule included?"""
     bm, bn, bk = blocks
-    if bm <= 0 or bn <= 0 or bk <= 0 or n % bn != 0:
+    if bm <= 0 or bn <= 0 or bk <= 0 or n % bn != 0 or bn % LANE != 0:
         return False
-    if kind in ("dual_decode", "dual_decode_fused"):
+    if kind.startswith("dual") and group % LANE != 0:
+        return False
+    if kind in _DECODE_KINDS:
         return k % group == 0
-    return k % bk == 0 and bk % group == 0
+    return (
+        bm % SUBLANE == 0 and k % bk == 0 and bk % group == 0
+        and (bk % LANE == 0 or bk == k)
+    )
 
 
 def get_blocks(

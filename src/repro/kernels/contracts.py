@@ -16,6 +16,12 @@ This module is the machine-checked version of those invariants:
   the kernel wrappers. They raise :class:`ContractError` (a ``ValueError``)
   with the violated relation, the offending values, and a hint — BEFORE
   ``pl.pallas_call`` hands the launch to Mosaic.
+* :func:`check_blocks` / :func:`lane_aligned` — the TPU layout rules that
+  interpret mode does not enforce: every BlockSpec block's last two dims are
+  multiples of (8, 128) or equal the array's (the (8, 128) block rule), and
+  a value stored or loaded at a dynamic lane offset spans whole 128-lane
+  tiles (the lane-aligned store rule). The validators apply both, so a
+  launch the chip's compiler would refuse raises here on every backend.
 * :func:`vmem_footprint` / :func:`check_vmem` — a per-launch VMEM estimate
   computed from the kernel's BlockSpec block shapes and scratch shapes
   (streamed operands double-buffered, pinned/constant-index operands counted
@@ -49,10 +55,12 @@ __all__ = [
     "check_paged_decode_args",
     "check_ragged_args",
     "check_twinquant_group_pack",
+    "check_blocks",
     "check_twinquant_pack",
     "check_vmem",
     "check_w4a16_pack",
     "divisible",
+    "lane_aligned",
     "validate_dual_gemm",
     "validate_dual_gemm_group",
     "validate_dual_gemv",
@@ -100,6 +108,56 @@ def divisible(a: int, b: int, what: str, *, kind: str, hint: str = "") -> None:
 def positive(value: int, what: str, *, kind: str) -> None:
     if value <= 0:
         raise ContractError(f"[{kind}] {what} must be positive, got {value}")
+
+
+# ---------------------------------------------------------------------------
+# TPU layout rules (Mosaic refuses these; interpret mode runs them anyway)
+# ---------------------------------------------------------------------------
+
+SUBLANE = 8  # rows of one (f32) vreg tile
+LANE = 128  # lanes of one vreg tile
+
+
+def check_blocks(
+    kind: str, specs: Sequence[tuple[str, tuple[int, ...], tuple[int, ...]]],
+) -> None:
+    """The (8, 128) block rule for each ``(name, block_shape, array_shape)``:
+    a block's last two dims must each be a multiple of (8, 128) or equal the
+    array's dim. Leading dims are untiled and unconstrained."""
+    for name, block, array in specs:
+        for axis, tile in ((-2, SUBLANE), (-1, LANE)):
+            if len(block) < -axis:
+                continue
+            b, a = block[axis], array[axis]
+            if b != a and b % tile != 0:
+                raise ContractError(
+                    f"[{kind}] {name}: block {tuple(block)} over array "
+                    f"{tuple(array)} breaks the (8, 128) block rule: dim "
+                    f"{axis} is {b}, neither a multiple of {tile} nor the "
+                    f"array's {a}\n  hint: pick blocks from "
+                    "autotune.heuristic_blocks, which only yields legal ones"
+                )
+
+
+def lane_aligned(width: int, what: str, *, kind: str) -> None:
+    """The lane-aligned store rule: a value stored or loaded at a dynamic
+    lane offset must span whole 128-lane tiles (Mosaic cannot prove a
+    narrower dynamic offset aligned and refuses the access)."""
+    divisible(width, LANE, what, kind=kind,
+              hint="this width is sliced at a dynamic lane offset in-kernel; "
+              "the TPU moves whole 128-lane tiles there")
+
+
+def _rows(n: int, dtype) -> int:
+    """``n`` rounded up to whole sublane tiles of ``dtype`` (8 rows of f32,
+    16 of bf16, 32 of int8) — what a small second-minor dim occupies."""
+    tile = SUBLANE * 4 // jnp.dtype(dtype).itemsize
+    return -(-n // tile) * tile
+
+
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane tiles."""
+    return -(-n // LANE) * LANE
 
 
 # ---------------------------------------------------------------------------
@@ -187,6 +245,15 @@ def validate_dual_gemm(
               hint="H requantization tiles the rank axis by rgroup")
     divisible(rgroup, 2, "rgroup % 2", kind=kind,
               hint="V is group-split packed along the rank axis")
+    lane_aligned(group, "group % 128", kind=kind)
+    check_blocks(kind, [
+        ("x", (block_m, block_k), (m, k)),
+        ("vp", (r // 2, block_n), (r // 2, n)),
+        ("vs", (r // rgroup, block_n), (r // rgroup, n)),
+        ("rp", (block_k // 2, block_n), (k // 2, n)),
+        ("rs", (k // group, block_n), (k // group, n)),
+        ("out", (block_m, block_n), (m, n)),
+    ])
     check_vmem(kind, [
         ("x", (block_m, block_k), jnp.bfloat16, "streamed"),
         ("up", (k // 2, r), jnp.int8, "pinned"),
@@ -194,10 +261,11 @@ def validate_dual_gemm(
         ("vp", (r // 2, block_n), jnp.int8, "streamed"),
         ("vs", (r // rgroup, block_n), jnp.float32, "streamed"),
         ("rp", (block_k // 2, block_n), jnp.int8, "streamed"),
-        ("rs", (block_k // group, block_n), jnp.float32, "streamed"),
+        ("rs", (k // group, block_n), jnp.float32, "streamed"),
         ("out", (block_m, block_n), jnp.bfloat16, "out"),
         ("xq_s", (block_m, k), jnp.int8, "scratch"),
-        ("xs_s", (block_m, k // group), jnp.float32, "scratch"),
+        ("xs_s", (k // block_k, block_m, _lanes(block_k // group)),
+         jnp.float32, "scratch"),
         ("h_s", (block_m, r), jnp.float32, "scratch"),
         ("hq_s", (block_m, r), jnp.int8, "scratch"),
         ("hs_s", (block_m, r // rgroup), jnp.float32, "scratch"),
@@ -227,6 +295,13 @@ def validate_dual_gemv(
               hint="H requantization tiles the rank axis by rgroup")
     divisible(rgroup, 2, "rgroup % 2", kind=kind,
               hint="V is group-split packed along the rank axis")
+    lane_aligned(group, "group % 128", kind=kind)
+    lane_aligned(block_n, "block_n % 128", kind=kind)
+    check_blocks(kind, [
+        ("rp", (k // 2, block_n), (k // 2, n)),
+        ("rs", (k // group, block_n), (k // group, n)),
+        ("out", (m, block_n), (m, n)),
+    ])
     check_vmem(kind, [
         ("x", (m, k), jnp.bfloat16, "pinned"),
         ("up", (k // 2, r), jnp.int8, "pinned"),
@@ -237,7 +312,8 @@ def validate_dual_gemv(
         ("rs", (k // group, block_n), jnp.float32, "streamed"),
         ("out", (m, block_n), jnp.bfloat16, "out"),
         ("xq_s", (m, k), jnp.int8, "scratch"),
-        ("xs_s", (m, k // group), jnp.float32, "scratch"),
+        # one (m, 1) slab per group, each a whole padded vreg tile
+        ("xs_s", (k // group, _rows(m, jnp.float32), LANE), jnp.float32, "scratch"),
         ("hq_s", (m, r), jnp.int8, "scratch"),
         ("hs_s", (m, r // rgroup), jnp.float32, "scratch"),
     ], budget=budget)
@@ -278,17 +354,27 @@ def validate_dual_gemm_group(
     divisible(group, 2, "group % 2", kind=kind,
               hint="group-split nibble packing pairs rows inside a group")
     _validate_segments(seg_n, seg_r, rgroups, block_n, kind=kind)
+    lane_aligned(group, "group % 128", kind=kind)
+    lane_aligned(block_n, "block_n % 128", kind=kind)
     r_total = sum(seg_r)
+    n_total = sum(seg_n)
+    check_blocks(kind, [
+        ("x", (block_m, block_k), (m, k)),
+        ("rp", (block_k // 2, block_n), (k // 2, n_total)),
+        ("rs", (k // group, block_n), (k // group, n_total)),
+        ("out", (block_m, block_n), (m, n_total)),
+    ])
     hs_cols = sum(rj // gr for rj, gr in zip(seg_r, rgroups))
     bufs = [
         ("x", (block_m, block_k), jnp.bfloat16, "streamed"),
         ("up", (k // 2, r_total), jnp.int8, "pinned"),
         ("us", (k // group, r_total), jnp.float32, "pinned"),
         ("rp", (block_k // 2, block_n), jnp.int8, "streamed"),
-        ("rs", (block_k // group, block_n), jnp.float32, "streamed"),
+        ("rs", (k // group, block_n), jnp.float32, "streamed"),
         ("out", (block_m, block_n), jnp.bfloat16, "out"),
         ("xq_s", (block_m, k), jnp.int8, "scratch"),
-        ("xs_s", (block_m, k // group), jnp.float32, "scratch"),
+        ("xs_s", (k // block_k, block_m, _lanes(block_k // group)),
+         jnp.float32, "scratch"),
         ("h_s", (block_m, r_total), jnp.float32, "scratch"),
         ("hq_s", (block_m, r_total), jnp.int8, "scratch"),
         ("hs_s", (block_m, hs_cols), jnp.float32, "scratch"),
@@ -320,7 +406,15 @@ def validate_dual_gemv_group(
     divisible(group, 2, "group % 2", kind=kind,
               hint="group-split nibble packing pairs rows inside a group")
     _validate_segments(seg_n, seg_r, rgroups, block_n, kind=kind)
+    lane_aligned(group, "group % 128", kind=kind)
+    lane_aligned(block_n, "block_n % 128", kind=kind)
     r_total = sum(seg_r)
+    n_total = sum(seg_n)
+    check_blocks(kind, [
+        ("rp", (k // 2, block_n), (k // 2, n_total)),
+        ("rs", (k // group, block_n), (k // group, n_total)),
+        ("out", (m, block_n), (m, n_total)),
+    ])
     hs_cols = sum(rj // gr for rj, gr in zip(seg_r, rgroups))
     bufs = [
         ("x", (m, k), jnp.bfloat16, "pinned"),
@@ -330,7 +424,8 @@ def validate_dual_gemv_group(
         ("rs", (k // group, block_n), jnp.float32, "streamed"),
         ("out", (m, block_n), jnp.bfloat16, "out"),
         ("xq_s", (m, k), jnp.int8, "scratch"),
-        ("xs_s", (m, k // group), jnp.float32, "scratch"),
+        # one (m, 1) slab per group, each a whole padded vreg tile
+        ("xs_s", (k // group, _rows(m, jnp.float32), LANE), jnp.float32, "scratch"),
         ("hq_s", (m, r_total), jnp.int8, "scratch"),
         ("hs_s", (m, hs_cols), jnp.float32, "scratch"),
     ]
@@ -356,10 +451,16 @@ def validate_w4a16(
               hint="every K block must hold whole scale groups")
     divisible(group, 2, "group % 2", kind=kind,
               hint="group-split nibble packing pairs rows inside a group")
+    check_blocks(kind, [
+        ("x", (block_m, block_k), (m, k)),
+        ("wp", (block_k // 2, block_n), (k // 2, n)),
+        ("ws", (k // group, block_n), (k // group, n)),
+        ("out", (block_m, block_n), (m, n)),
+    ])
     check_vmem(kind, [
         ("x", (block_m, block_k), jnp.bfloat16, "streamed"),
         ("wp", (block_k // 2, block_n), jnp.int8, "streamed"),
-        ("ws", (block_k // group, block_n), jnp.float32, "streamed"),
+        ("ws", (k // group, block_n), jnp.float32, "streamed"),
         ("out", (block_m, block_n), jnp.bfloat16, "out"),
         ("acc_s", (block_m, block_n), jnp.float32, "scratch"),
     ], budget=budget)
@@ -432,8 +533,10 @@ def validate_paged_decode(
         ("v_page", (1, page, kvh * hd), jnp.bfloat16, "streamed"),
         ("k_tok", (t2, kvh * hd), jnp.bfloat16, "pinned"),
         ("v_tok", (t2, kvh * hd), jnp.bfloat16, "pinned"),
-        ("k_slot", (sq, kvh * hd), jnp.bfloat16, "streamed"),
-        ("v_slot", (sq, kvh * hd), jnp.bfloat16, "streamed"),
+        # slot-major (B, sq, KV*hd) view: each slot's rows pad to a whole
+        # bf16 sublane tile
+        ("k_slot", (b, _rows(sq, jnp.bfloat16), kvh * hd), jnp.bfloat16, "pinned"),
+        ("v_slot", (b, _rows(sq, jnp.bfloat16), kvh * hd), jnp.bfloat16, "pinned"),
         ("meta", (t2,), jnp.int32, "pinned"),
         ("out", (t2, h * hd), jnp.bfloat16, "out"),
         ("k_tail", (1, page, kvh * hd), jnp.bfloat16, "out"),

@@ -63,8 +63,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.contracts import validate_paged_decode
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both vintages
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["paged_decode_kernel", "paged_decode_ref", "scatter_rows_pool"]
 
@@ -181,8 +179,8 @@ def _paged_decode_fwd(
     vp_ref,  # (1, page, KV*hd) bf16 — one V page, streamed via bt / tails
     kt_ref,  # (B*sq, KV*hd) bf16 — draft K rows, resident
     vt_ref,  # (B*sq, KV*hd) bf16 — draft V rows, resident
-    kslot_ref,  # (sq, KV*hd) bf16 — current slot's draft K rows (BlockSpec slice)
-    vslot_ref,  # (sq, KV*hd) bf16
+    kslot_ref,  # (B, sq, KV*hd) bf16 — the same draft K rows, slot-major
+    vslot_ref,  # (B, sq, KV*hd) bf16
     pos_c_ref,  # (B*sq, 1) int32 — per-row committed prefix length
     # outputs
     o_ref,  # (B*sq, H*hd) bf16
@@ -268,14 +266,20 @@ def _paged_decode_fwd(
             off_iota = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
             k_acc = kp_ref[0]
             v_acc = vp_ref[0]
+            # slot b's draft rows: the dynamic slot index lands on the untiled
+            # leading axis (a per-slot (sq, KV*hd) BlockSpec would break the
+            # (8, 128) block rule for sq < 8, and a dynamic one-row slice of
+            # the bf16 panel is not sublane-aligned)
+            k_rows = kslot_ref[b]
+            v_rows = vslot_ref[b]
             for i in range(sq):
                 abs_i = pos_b + i
                 pi = abs_i // page
                 mapped = bt_ref[b, jnp.where(pi < maxp, pi, 0)] >= 0
                 ok = (pi == this_col) & (pi < maxp) & mapped
                 sel = (off_iota == (abs_i - pi * page)) & ok  # (page, 1)
-                k_acc = jnp.where(sel, kslot_ref[i : i + 1, :], k_acc)
-                v_acc = jnp.where(sel, vslot_ref[i : i + 1, :], v_acc)
+                k_acc = jnp.where(sel, k_rows[i : i + 1, :], k_acc)
+                v_acc = jnp.where(sel, v_rows[i : i + 1, :], v_acc)
             kp_o_ref[0] = k_acc
             vp_o_ref[0] = v_acc
 
@@ -375,10 +379,8 @@ def paged_decode_kernel(q, kp, vp, kt, vt, bt, pos, *, commit: bool = True,
             ),
             pl.BlockSpec((t2, kv * hd), lambda bi, ji, bts, tls, pp: (0, 0)),
             pl.BlockSpec((t2, kv * hd), lambda bi, ji, bts, tls, pp: (0, 0)),
-            # the current slot's own draft rows, sliced out by the BlockSpec
-            # so the epilogue indexes them statically
-            pl.BlockSpec((sq, kv * hd), lambda bi, ji, bts, tls, pp: (bi, 0)),
-            pl.BlockSpec((sq, kv * hd), lambda bi, ji, bts, tls, pp: (bi, 0)),
+            pl.BlockSpec((b, sq, kv * hd), lambda bi, ji, bts, tls, pp: (0, 0, 0)),
+            pl.BlockSpec((b, sq, kv * hd), lambda bi, ji, bts, tls, pp: (0, 0, 0)),
             pl.BlockSpec((t2, 1), lambda bi, ji, bts, tls, pp: (0, 0)),
         ],
         out_specs=[
@@ -432,11 +434,12 @@ def paged_decode_kernel(q, kp, vp, kt, vt, bt, pos, *, commit: bool = True,
         # operand order: bt, tails, posp, q2, kp2, vp2, kt2, vt2, kslot,
         # vslot, pos_c -> kp2/vp2 are operands 4/5, aliased onto outputs 1/2
         input_output_aliases={4: 1, 5: 2} if commit else {},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)
         ),
         interpret=interpret,
-    )(bt32, tails, pos32, q2, kp2, vp2, kt2, vt2, kt2, vt2, pos_c)
+    )(bt32, tails, pos32, q2, kp2, vp2, kt2, vt2,
+      kt.reshape(b, sq, kv * hd), vt.reshape(b, sq, kv * hd), pos_c)
     if commit:
         out, kp_new, vp_new = res
         return (

@@ -53,8 +53,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.contracts import validate_ragged_attention
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both vintages
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["ragged_attention_kernel", "ragged_attention_ref"]
 
@@ -295,7 +293,7 @@ def ragged_attention_kernel(q, kp, vp, kt, vt, bt, slot, pos, ctx, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, h * hd), vt.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)
         ),
         interpret=interpret,

@@ -17,6 +17,16 @@ for a TwinQuant-decomposed linear layer, with:
 * both component outputs merged in a **single epilogue** with one bf16
   write-back per output tile.
 
+Layout rules the TPU compiler enforces (interpret mode does not):
+
+* the K block's activation scales are assembled into one ``(bm, bk/G)``
+  value and stored whole into a K-block-major scratch ``(K/bk, bm, bk/G)``:
+  the grid's dynamic K index lands on the untiled leading axis, so no store
+  is narrower than a lane tile at a dynamic lane offset;
+* the residual scales ``rs`` arrive as a whole-K ``(K/G, bn)`` strip per N
+  block (the full K/G extent satisfies the (8, 128) block rule for any
+  ``block_k``) and the kernel slices its K block's rows out of it.
+
 Grid is ``(M/bm, N/bn, K/bk)`` with K innermost
 (``dimension_semantics = (parallel, arbitrary, arbitrary)``). HBM traffic:
 
@@ -41,9 +51,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both vintages
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 from repro.core.quantization import qmax_for_bits
 from repro.kernels.contracts import validate_dual_gemm, validate_dual_gemm_group
@@ -76,12 +83,12 @@ def _dual_gemm_kernel(
     vp_ref,  # (r/2, bn) int8 packed
     vs_ref,  # (r/gr, bn) f32
     rp_ref,  # (bk/2, bn) int8 packed
-    rs_ref,  # (bk/G, bn) f32
+    rs_ref,  # (K/G, bn) f32 — whole-K strip, fetched once per N block
     # output
     o_ref,  # (bm, bn)  bf16
     # scratch
     xq_s,  # (bm, K)    int8 — quantized activation row-panel
-    xs_s,  # (bm, K/G)  f32  — its per-group scales
+    xs_s,  # (K/bk, bm, bk/G) f32 — its per-group scales, K-block-major
     h_s,  # (bm, r)     f32  — low-rank intermediate accumulator
     hq_s,  # (bm, r)    int8 — requantized H
     hs_s,  # (bm, r/gr) f32  — H scales
@@ -112,25 +119,29 @@ def _dual_gemm_kernel(
     @pl.when(n == 0)
     def _quantize_and_lowrank():
         x = x_ref[...].astype(jnp.float32)  # (bm, bk)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], gpb), 1)
+        scales = jnp.zeros((x.shape[0], gpb), jnp.float32)
         for g in range(gpb):
             xg = x[:, g * G : (g + 1) * G]
             amax = jnp.max(jnp.abs(xg), axis=1, keepdims=True)  # (bm, 1)
             scale = jnp.where(amax > 0, amax / a_qmax, 1.0)
             q = jnp.clip(jnp.round(xg / scale), -a_qmax, a_qmax).astype(jnp.int8)
             xq_s[:, pl.ds(k * bk + g * G, G)] = q
-            xs_s[:, pl.ds(k * gpb + g, 1)] = scale
+            scales = jnp.where(lane == g, scale, scales)
             # first low-rank GEMM on the freshly quantized group
-            ug = _unpack_rows(up_ref[pl.ds((k * bk + g * G) // 2, G // 2), :])  # (G, r)
+            ug = _unpack_rows(up_ref[pl.ds(k * (bk // 2) + g * (G // 2), G // 2), :])  # (G, r)
             us = us_ref[pl.ds(k * gpb + g, 1), :]  # (1, r)
             ph = _int8_dot(q, ug).astype(jnp.float32)
             h_s[...] += ph * scale * us
+        xs_s[k] = scales
 
     # ---- stage B: residual-component partial for this (n, k) tile
+    sk = xs_s[k]  # (bm, gpb)
     for g in range(gpb):
         xg = xq_s[:, pl.ds(k * bk + g * G, G)]  # (bm, G) int8
-        sg = xs_s[:, pl.ds(k * gpb + g, 1)]  # (bm, 1)
+        sg = sk[:, g : g + 1]  # (bm, 1)
         rg = _unpack_rows(rp_ref[g * (G // 2) : (g + 1) * (G // 2), :])  # (G, bn)
-        rs = rs_ref[g : g + 1, :]  # (1, bn)
+        rs = rs_ref[pl.ds(k * gpb + g, 1), :]  # (1, bn)
         pr = _int8_dot(xg, rg).astype(jnp.float32)
         acc_s[...] += pr * sg * rs
 
@@ -208,19 +219,19 @@ def dual_gemm(
             pl.BlockSpec((r // 2, block_n), lambda mi, ni, ki: (0, ni)),
             pl.BlockSpec((r // gr, block_n), lambda mi, ni, ki: (0, ni)),
             pl.BlockSpec((block_k // 2, block_n), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((block_k // G, block_n), lambda mi, ni, ki: (ki, ni)),
+            pl.BlockSpec((k // G, block_n), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.bfloat16),
         scratch_shapes=[
             pltpu.VMEM((block_m, k), jnp.int8),
-            pltpu.VMEM((block_m, k // G), jnp.float32),
+            pltpu.VMEM((n_k, block_m, block_k // G), jnp.float32),
             pltpu.VMEM((block_m, r), jnp.float32),
             pltpu.VMEM((block_m, r), jnp.int8),
             pltpu.VMEM((block_m, r // gr), jnp.float32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,
@@ -303,24 +314,28 @@ def dual_gemm_group(
         @pl.when(ni == 0)
         def _quantize_and_lowrank():
             xv = x_ref[...].astype(jnp.float32)  # (bm, bk)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (bm, gpb), 1)
+            scales = jnp.zeros((bm, gpb), jnp.float32)
             for g in range(gpb):
                 xg = xv[:, g * G : (g + 1) * G]
                 amax = jnp.max(jnp.abs(xg), axis=1, keepdims=True)
                 scale = jnp.where(amax > 0, amax / a_qmax, 1.0)
                 q = jnp.clip(jnp.round(xg / scale), -a_qmax, a_qmax).astype(jnp.int8)
                 xq_s[:, pl.ds(ki * bk + g * G, G)] = q
-                xs_s[:, pl.ds(ki * gpb + g, 1)] = scale
-                ug = _unpack_rows(up_ref[pl.ds((ki * bk + g * G) // 2, G // 2), :])
+                scales = jnp.where(lane == g, scale, scales)
+                ug = _unpack_rows(up_ref[pl.ds(ki * (bk // 2) + g * (G // 2), G // 2), :])
                 us = us_ref[pl.ds(ki * gpb + g, 1), :]
                 ph = _int8_dot(q, ug).astype(jnp.float32)
                 h_s[...] += ph * scale * us
+            xs_s[ki] = scales
 
         # ---- stage B: residual partial for this (concatenated-N, K) tile
+        sk = xs_s[ki]  # (bm, gpb)
         for g in range(gpb):
             xg = xq_s[:, pl.ds(ki * bk + g * G, G)]
-            sg = xs_s[:, pl.ds(ki * gpb + g, 1)]
+            sg = sk[:, g : g + 1]
             rg = _unpack_rows(rp_ref[g * (G // 2) : (g + 1) * (G // 2), :])
-            rs = rs_ref[g : g + 1, :]
+            rs = rs_ref[pl.ds(ki * gpb + g, 1), :]
             pr = _int8_dot(xg, rg).astype(jnp.float32)
             acc_s[...] += pr * sg * rs
 
@@ -380,7 +395,7 @@ def dual_gemm_group(
         in_specs.append(pl.BlockSpec(vs.shape, lambda mi, ni, ki: (0, 0)))
     in_specs += [
         pl.BlockSpec((bk // 2, bn), lambda mi, ni, ki: (ki, ni)),
-        pl.BlockSpec((bk // G, bn), lambda mi, ni, ki: (ki, ni)),
+        pl.BlockSpec((k // G, bn), lambda mi, ni, ki: (0, ni)),
     ]
     operands = [x, gw.up, gw.us]
     for vp, vs in zip(gw.vps, gw.vss):
@@ -395,13 +410,13 @@ def dual_gemm_group(
         out_shape=jax.ShapeDtypeStruct((m, n_total), jnp.bfloat16),
         scratch_shapes=[
             pltpu.VMEM((bm, k), jnp.int8),
-            pltpu.VMEM((bm, k // G), jnp.float32),
+            pltpu.VMEM((n_k, bm, gpb), jnp.float32),
             pltpu.VMEM((bm, r_total), jnp.float32),
             pltpu.VMEM((bm, r_total), jnp.int8),
             pltpu.VMEM((bm, hs_cols), jnp.float32),
             pltpu.VMEM((bm, bn), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,

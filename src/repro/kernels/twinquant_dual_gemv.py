@@ -17,6 +17,10 @@ This kernel is the decode-matched schedule:
   low-rank intermediate ``H = requant(dq(Xq @ Uq))`` is computed and
   requantized once, at the first grid step, and every N block only pays the
   tiny (m, r) x (r, bn) second GEMM in its epilogue;
+* each scale group's activation scale lives in its own ``(m, 1)`` slab of a
+  group-major scratch, so the fori_loop's dynamic group index lands on the
+  untiled leading axis: no store is narrower than a lane tile at a dynamic
+  lane offset (Mosaic refuses those);
 * the grid is **one-dimensional over N** (``(N/bn,)``): each step streams a
   whole-K ``(K/2, bn)`` packed residual tile — the only HBM traffic that
   scales with N — computes the residual component with a fori_loop over
@@ -41,8 +45,6 @@ from repro.kernels.autotune import DECODE_M_MAX
 from repro.kernels.contracts import validate_dual_gemv, validate_dual_gemv_group
 from repro.kernels.ref import TwinQuantGroupWeights, TwinQuantWeights
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both vintages
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = ["dual_gemv", "dual_gemv_group", "DECODE_M_MAX"]
 
@@ -74,7 +76,7 @@ def _dual_gemv_kernel(
     o_ref,  # (m, bn)    bf16
     # scratch (persist across the sequential N grid)
     xq_s,  # (m, K)      int8 — quantized activation panel
-    xs_s,  # (m, K/G)    f32  — its per-group scales
+    xs_s,  # (K/G, m, 1) f32  — its per-group scales, group-major
     hq_s,  # (m, r)      int8 — requantized low-rank intermediate
     hs_s,  # (m, r/gr)   f32  — its scales
     *,
@@ -101,7 +103,7 @@ def _dual_gemv_kernel(
             scale = jnp.where(amax > 0, amax / a_qmax, 1.0)
             q = jnp.clip(jnp.round(xg / scale), -a_qmax, a_qmax).astype(jnp.int8)
             xq_s[:, pl.ds(g * G, G)] = q
-            xs_s[:, pl.ds(g, 1)] = scale
+            xs_s[g] = scale
             ug = _unpack_rows(up_ref[pl.ds(g * (G // 2), G // 2), :])  # (G, r)
             us = us_ref[pl.ds(g, 1), :]  # (1, r)
             return h + _int8_dot(q, ug).astype(jnp.float32) * scale * us
@@ -119,7 +121,7 @@ def _dual_gemv_kernel(
     # ---- every grid step: whole-K residual component for this N block
     def resid(g, acc):
         xg = xq_s[:, pl.ds(g * G, G)]  # (m, G) int8
-        sg = xs_s[:, pl.ds(g, 1)]  # (m, 1)
+        sg = xs_s[g]  # (m, 1)
         rg = _unpack_rows(rp_ref[pl.ds(g * (G // 2), G // 2), :])  # (G, bn)
         rs = rs_ref[pl.ds(g, 1), :]  # (1, bn)
         return acc + _int8_dot(xg, rg).astype(jnp.float32) * sg * rs
@@ -179,11 +181,11 @@ def dual_gemv(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.bfloat16),
         scratch_shapes=[
             pltpu.VMEM((m, k), jnp.int8),
-            pltpu.VMEM((m, k // G), jnp.float32),
+            pltpu.VMEM((k // G, m, 1), jnp.float32),
             pltpu.VMEM((m, r), jnp.int8),
             pltpu.VMEM((m, r // gr), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential N sweep: scratch (Xq, H) persists across grid steps
             dimension_semantics=(pltpu.ARBITRARY,),
         ),
@@ -258,7 +260,7 @@ def dual_gemv_group(
                 scale = jnp.where(amax > 0, amax / a_qmax, 1.0)
                 q = jnp.clip(jnp.round(xg / scale), -a_qmax, a_qmax).astype(jnp.int8)
                 xq_s[:, pl.ds(g * G, G)] = q
-                xs_s[:, pl.ds(g, 1)] = scale
+                xs_s[g] = scale
                 ug = _unpack_rows(up_ref[pl.ds(g * (G // 2), G // 2), :])
                 us = us_ref[pl.ds(g, 1), :]
                 return h + _int8_dot(q, ug).astype(jnp.float32) * scale * us
@@ -279,7 +281,7 @@ def dual_gemv_group(
         # ---- every grid step: whole-K residual for this (concatenated) N block
         def resid(g, acc):
             xg = xq_s[:, pl.ds(g * G, G)]
-            sg = xs_s[:, pl.ds(g, 1)]
+            sg = xs_s[g]
             rg = _unpack_rows(rp_ref[pl.ds(g * (G // 2), G // 2), :])
             rs = rs_ref[pl.ds(g, 1), :]
             return acc + _int8_dot(xg, rg).astype(jnp.float32) * sg * rs
@@ -337,11 +339,11 @@ def dual_gemv_group(
         out_shape=jax.ShapeDtypeStruct((m, n_total), jnp.bfloat16),
         scratch_shapes=[
             pltpu.VMEM((m, k), jnp.int8),
-            pltpu.VMEM((m, k // G), jnp.float32),
+            pltpu.VMEM((k // G, m, 1), jnp.float32),
             pltpu.VMEM((m, r_total), jnp.int8),
             pltpu.VMEM((m, hs_cols), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential N sweep: scratch (Xq, H) persists across grid steps
             dimension_semantics=(pltpu.ARBITRARY,),
         ),

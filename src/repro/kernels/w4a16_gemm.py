@@ -16,9 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both vintages
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 from repro.kernels.contracts import validate_w4a16
 
 __all__ = ["w4a16_gemm"]
@@ -42,7 +39,7 @@ def _w4a16_kernel(x_ref, wp_ref, ws_ref, o_ref, acc_s, *, bk: int, G: int, n_k: 
     xb = x_ref[...].astype(jnp.bfloat16)
     for g in range(gpb):
         wg = _unpack_rows(wp_ref[g * (G // 2) : (g + 1) * (G // 2), :])  # (G, bn)
-        sg = ws_ref[g : g + 1, :]  # (1, bn)
+        sg = ws_ref[pl.ds(k * gpb + g, 1), :]  # (1, bn)
         w_deq = (wg.astype(jnp.float32) * sg).astype(jnp.bfloat16)
         xg = xb[:, g * G : (g + 1) * G]
         acc_s[...] += jax.lax.dot_general(
@@ -81,12 +78,15 @@ def w4a16_gemm(
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((block_k // 2, block_n), lambda mi, ni, ki: (ki, ni)),
-            pl.BlockSpec((block_k // group, block_n), lambda mi, ni, ki: (ki, ni)),
+            # whole-K scale strip: the full K/G extent keeps the block legal
+            # for any block_k (a (block_k/G, block_n) block breaks the (8, 128)
+            # rule below block_k = 8 * group)
+            pl.BlockSpec((k // group, block_n), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.bfloat16),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,

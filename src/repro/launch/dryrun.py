@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, ModelConfig, QuantSpec, get_config
 from repro.core.twinquant import quantize_params
-from repro.launch.mesh import dp_axes, make_production_mesh, use_mesh
+from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.launch.roofline import Roofline
 from repro.launch.sharding import batch_specs, decode_state_specs, make_shardings, param_specs
 from repro.launch.train import make_train_step
@@ -92,7 +92,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, quant: str,
     bspecs = batch_specs(cfg, batch_sds, ctx)
     bshard = make_shardings(mesh, bspecs)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if spec["kind"] == "train":
             opt = AdamW(moment_dtype=jnp.bfloat16 if "671b" in arch else jnp.float32)
             opt_sds = jax.eval_shape(opt.init, params_sds)

@@ -165,20 +165,10 @@ def linear(p: dict, x: jax.Array) -> jax.Array:
         return y
     if "rp" in p:  # TwinQuant dual-component pack
         from repro.kernels.dispatch import quant_linear
-        from repro.kernels.ref import TwinQuantWeights
 
-        # static metadata is encoded in (static) shapes: scale-group sizes
-        # from packed-vs-scale row ratios, activation bits from the `abits`
-        # marker array's length — keeps the params pytree jit-pure
-        w = TwinQuantWeights(
-            up=p["up"], us=p["us"], vp=p["vp"], vs=p["vs"], rp=p["rp"], rs=p["rs"],
-            group=p["rp"].shape[-2] * 2 // p["rs"].shape[-2],
-            rgroup=p["vp"].shape[-2] * 2 // p["vs"].shape[-2],
-            a_bits=p["abits"].shape[-1],
-        )
         # routed by shape regime (prefill / decode / ref) at trace time; on
         # CPU the routed schedule executes with oracle numerics (dispatch.py)
-        return quant_linear(x, w, p.get("b")).astype(x.dtype)
+        return quant_linear(x, weights_of(p), p.get("b")).astype(x.dtype)
     if "wp" in p:  # W4A16 weight-only pack
         from repro.kernels.dispatch import w4a16_linear
 
@@ -189,11 +179,27 @@ def linear(p: dict, x: jax.Array) -> jax.Array:
     raise KeyError(f"unrecognized linear params: {sorted(p)}")
 
 
-def _group_weights_of(fp: dict):
+def weights_of(p: dict):
+    """Pack param dict ({up,us,vp,vs,rp,rs,abits}) -> :class:`TwinQuantWeights`.
+
+    Static metadata is encoded in (static) shapes: scale-group sizes from
+    packed-vs-scale row ratios, activation bits from the ``abits`` marker
+    array's length — keeps the params pytree jit-pure."""
+    from repro.kernels.ref import TwinQuantWeights
+
+    return TwinQuantWeights(
+        up=p["up"], us=p["us"], vp=p["vp"], vs=p["vs"], rp=p["rp"], rs=p["rs"],
+        group=p["rp"].shape[-2] * 2 // p["rs"].shape[-2],
+        rgroup=p["vp"].shape[-2] * 2 // p["vs"].shape[-2],
+        a_bits=p["abits"].shape[-1],
+    )
+
+
+def group_weights_of(fp: dict):
     """Fused-pack param dict ({up,us,rp,rs,abits,vp0,vs0,...}) -> group pack.
 
-    Like the single-pack branch of :func:`linear`, all static metadata is
-    recovered from (static) array shapes so the params pytree stays jit-pure.
+    Like :func:`weights_of`, all static metadata is recovered from (static)
+    array shapes so the params pytree stays jit-pure.
     """
     from repro.kernels.ref import TwinQuantGroupWeights
 
@@ -254,7 +260,7 @@ def linear_group(p: dict, names: tuple, fused_key: str, x: jax.Array) -> tuple:
 
     fp = p.get(fused_key)
     if fp is not None:
-        gw = _group_weights_of(fp)
+        gw = group_weights_of(fp)
         biases = gw.split(fp["b"]) if "b" in fp else (None,) * gw.n_segments
         if not fusion_enabled():  # A/B lane: per-segment launches
             return tuple(
@@ -266,19 +272,8 @@ def linear_group(p: dict, names: tuple, fused_key: str, x: jax.Array) -> tuple:
         )
     ps = [p[n] for n in names]
     if fusion_enabled() and _fusable_packs(ps):
-        from repro.kernels.ref import TwinQuantWeights
-
-        ws = [
-            TwinQuantWeights(
-                up=pp["up"], us=pp["us"], vp=pp["vp"], vs=pp["vs"],
-                rp=pp["rp"], rs=pp["rs"],
-                group=pp["rp"].shape[-2] * 2 // pp["rs"].shape[-2],
-                rgroup=pp["vp"].shape[-2] * 2 // pp["vs"].shape[-2],
-                a_bits=pp["abits"].shape[-1],
-            )
-            for pp in ps
-        ]
-        ys = fused_linear(x, ws, biases=[pp.get("b") for pp in ps])
+        ys = fused_linear(x, [weights_of(pp) for pp in ps],
+                          biases=[pp.get("b") for pp in ps])
         return tuple(y.astype(x.dtype) for y in ys)
     return tuple(linear(pp, x) for pp in ps)
 

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs import ModelConfig
 from repro.models import common as C
@@ -196,7 +195,7 @@ def _moe_ep(p: dict, x: jax.Array, cfg: ModelConfig):
             xx, rw, g, u, dn, cfg=cfg, ep_axis=ep_axis, ep_size=mesh.shape[ep_axis],
             fsdp_axes=fsdp, all_axes=tok_axes or (ep_axis,)
         )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -207,7 +206,7 @@ def _moe_ep(p: dict, x: jax.Array, cfg: ModelConfig):
             P(ep_axis, None, fs),  # down (E, F, D): D over fsdp
         ),
         out_specs=(P(tok_axes, None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["gate"]["w"], p["up"]["w"], p["down"]["w"])
     return out, jnp.mean(aux)
 

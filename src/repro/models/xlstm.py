@@ -278,7 +278,6 @@ def _slstm_cell_sharded(lp, x, cfg):
     of collectives at 4k seq — §Perf cell A iteration 3); shard_map keeps the
     recurrence batch-local and psums parameter gradients once at the exit."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.models.context import get_mesh_context
 
@@ -290,11 +289,11 @@ def _slstm_cell_sharded(lp, x, cfg):
     def body(lp_, x_):
         return _slstm_cell(lp_, x_, cfg)[0]
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(jax.tree.map(lambda _: P(), lp), P(dp, None, None)),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(lp, x)
 
 

@@ -12,9 +12,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.autotune import TuneCache, cache_key, get_blocks, heuristic_blocks
+from repro.kernels.autotune import (
+    TuneCache,
+    cache_key,
+    candidate_blocks,
+    get_blocks,
+    heuristic_blocks,
+)
 from repro.kernels.contracts import (
     ContractError,
+    check_blocks,
     check_vmem,
     validate_dual_gemm,
     validate_dual_gemm_group,
@@ -38,6 +45,7 @@ from repro.kernels.ref import (
 )
 from repro.kernels.twinquant_dual_gemm import dual_gemm
 from repro.kernels.twinquant_dual_gemv import dual_gemv
+from repro.kernels.w4a16_gemm import w4a16_gemm
 
 
 def _make_pack(key, K, N, r, a_bits=4, group=128):
@@ -94,6 +102,83 @@ def test_validate_group_segment_straddle():
 def test_validate_w4a16_violation():
     with pytest.raises(ContractError, match="K % block_k"):
         validate_w4a16(128, 256, 700, 128, 128, 256, 512)
+
+
+# ---------------------------------------------------------------------------
+# TPU layout rules: enforced at trace time on every backend
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("block,array,legal", [
+    ((4, 512), (32, 14336), False),    # block_k=512 over group 128: 4 scale rows
+    ((8, 512), (32, 14336), True),     # block_k=1024: 8 scale rows
+    ((4, 512), (4, 14336), True),      # the whole K/G extent
+    ((128, 64), (256, 512), False),    # block_n below a lane tile
+    ((4, 4096), (8, 4096), False),     # block_m below a sublane tile
+    ((8, 1, 1024), (8, 1, 1024), True),  # leading dims are untiled
+])
+def test_block_rule(block, array, legal):
+    specs = [("blk", block, array)]
+    if legal:
+        check_blocks("k", specs)
+    else:
+        with pytest.raises(ContractError, match=r"\(8, 128\) block rule"):
+            check_blocks("k", specs)
+
+
+def test_lane_rule_refuses_sub_lane_group():
+    """The dual kernels slice each scale group at a dynamic lane offset, so
+    a 64-wide group is refused by the validators and never routed to them;
+    the weight-only kernel slices groups statically and keeps it."""
+    with pytest.raises(ContractError, match="group % 128"):
+        validate_dual_gemv(8, 512, 1024, 64, 64, 32, 256, decode_m_max=8)
+    with pytest.raises(ContractError, match="group % 128"):
+        validate_dual_gemm(256, 512, 1024, 64, 64, 32, 128, 256, 512)
+    assert heuristic_blocks("dual_decode", 1, 128, 256, 64) is None
+    assert heuristic_blocks("dual_prefill", 256, 512, 1024, 64) is None
+    assert heuristic_blocks("w4a16", 256, 512, 1024, 64) is not None
+
+
+def test_kernel_wrapper_refuses_illegal_block_in_interpret_mode():
+    w, key = _make_pack(jax.random.PRNGKey(10), 512, 256, 32)
+    x = jax.random.normal(key, (128, 512)).astype(jnp.bfloat16)
+    with pytest.raises(ContractError, match="block rule"):
+        dual_gemm(x, w, block_m=128, block_n=64, block_k=512, interpret=True)
+    wq, ws = quantize_rows_ref(jax.random.normal(key, (512, 256)) * 0.1, 128, 4)
+    wp = pack_rows_groupsplit(wq, 128)
+    with pytest.raises(ContractError, match="block rule"):
+        w4a16_gemm(x[:8], wp, ws, group=128, block_m=4, block_n=128,
+                   block_k=128, interpret=True)
+
+
+@pytest.mark.parametrize("kind,m,n,k", [
+    ("dual_prefill", 256, 4096, 4096),
+    ("dual_prefill", 256, 4096, 14336),
+    ("dual_prefill", 64, 384, 768),
+    ("dual_prefill", 32, 256, 1536),
+    ("w4a16", 256, 14336, 4096),
+    ("w4a16", 16, 384, 640),
+])
+def test_candidate_blocks_pass_the_contract(kind, m, n, k):
+    """Every block triple the autotuner can hand the dispatch layer is one
+    the contract (and so the chip's compiler) accepts."""
+    group = 128
+    cands = candidate_blocks(kind, m, n, k, group, 128)
+    assert cands and heuristic_blocks(kind, m, n, k, group, 128) in cands
+    for bm, bn, bk in cands:
+        mp = -(-m // bm) * bm  # dispatch pads M to the block
+        if kind == "w4a16":
+            validate_w4a16(mp, n, k, group, bm, bn, bk, budget=2**40)
+        else:
+            validate_dual_gemm(mp, n, k, 128, group, 128, bm, bn, bk, budget=2**40)
+
+
+def test_stale_cache_entry_breaking_block_rule_degrades(tmp_path):
+    cache = TuneCache(tmp_path)
+    key = cache_key("w4a16", 256, 512, 1024, 64)
+    cache.store(key, (128, 512, 64))  # a 64-wide x block: not a lane tile
+    assert get_blocks("w4a16", 256, 512, 1024, 64, cache=TuneCache(tmp_path)) == \
+        heuristic_blocks("w4a16", 256, 512, 1024, 64)
 
 
 def test_kernel_wrapper_raises_contract_error_not_assert(monkeypatch):

@@ -209,7 +209,7 @@ def test_dispatch_counters_record_paths():
 
 @pytest.mark.parametrize("m,n,k,group", [
     (4, 4096, 4096, 128), (256, 14336, 4096, 128), (64, 384, 768, 128),
-    (8, 1024, 14336, 128), (1, 128, 256, 64),
+    (8, 1024, 14336, 128), (1, 128, 256, 256),
 ])
 def test_heuristic_blocks_valid_and_deterministic(m, n, k, group):
     kind = "dual_decode" if m <= DECODE_M_MAX else "dual_prefill"

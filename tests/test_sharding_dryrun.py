@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.launch.hlo_cost import analyze_hlo  # noqa: E402
-from repro.launch.mesh import use_mesh  # noqa: E402
+from repro.launch.mesh import make_debug_mesh  # noqa: E402
 from repro.launch.sharding import (  # noqa: E402
     batch_specs,
     decode_state_specs,
@@ -35,7 +35,7 @@ from repro.optim import AdamW  # noqa: E402
 def mesh_ctx():
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 forced host devices")
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_debug_mesh((2, 2))
     ctx = MeshContext(mesh=mesh, dp_axes=("data",), tp_axis="model",
                       ep_axis="model", fsdp_axes=("data",))
     set_mesh_context(ctx)
@@ -84,7 +84,7 @@ def test_train_step_compiles_sharded(mesh_ctx):
              "labels": jax.ShapeDtypeStruct((4, 64), jnp.int32)}
     bshard = make_shardings(mesh, batch_specs(cfg, batch, ctx))
     step = make_train_step(cfg, opt)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             step, in_shardings=(pshard, oshard, bshard),
             out_shardings=(pshard, oshard, NamedSharding(mesh, P())),
@@ -117,7 +117,7 @@ def test_ep_moe_collectives_present(mesh_ctx):
     batch = {"tokens": jax.ShapeDtypeStruct((4, 64), jnp.int32),
              "labels": jax.ShapeDtypeStruct((4, 64), jnp.int32)}
     bshard = make_shardings(mesh, batch_specs(cfg, batch, ctx))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             lambda p, b: model.loss_fn(p, cfg, b),
             in_shardings=(pshard, bshard),
