@@ -438,6 +438,7 @@ def paged_decode_kernel(q, kp, vp, kt, vt, bt, pos, *, commit: bool = True,
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)
         ),
         interpret=interpret,
+        name="paged_decode",
     )(bt32, tails, pos32, q2, kp2, vp2, kt2, vt2,
       kt.reshape(b, sq, kv * hd), vt.reshape(b, sq, kv * hd), pos_c)
     if commit:

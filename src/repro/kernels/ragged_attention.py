@@ -297,6 +297,7 @@ def ragged_attention_kernel(q, kp, vp, kt, vt, bt, slot, pos, ctx, *,
             dimension_semantics=(pltpu.ARBITRARY, pltpu.ARBITRARY)
         ),
         interpret=interpret,
+        name="ragged_attention",
     )(bt.astype(jnp.int32), q2, kp2, vp2, kt2, vt2,
       slot_c, pos_c, ctx_c, slot_r, pos_r)
     return out.reshape(t, h, hd)

@@ -235,6 +235,7 @@ def dual_gemm(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,
+        name="twinquant_dual_gemm",
     )(x, w.up, w.us, w.vp, w.vs, w.rp, w.rs)
 
 
@@ -420,4 +421,5 @@ def dual_gemm_group(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,
+        name="twinquant_dual_gemm_group",
     )(*operands)

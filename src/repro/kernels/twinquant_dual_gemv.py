@@ -190,6 +190,7 @@ def dual_gemv(
             dimension_semantics=(pltpu.ARBITRARY,),
         ),
         interpret=interpret,
+        name="twinquant_dual_gemv",
     )(x, w.up, w.us, w.vp, w.vs, w.rp, w.rs)
 
 
@@ -348,4 +349,5 @@ def dual_gemv_group(
             dimension_semantics=(pltpu.ARBITRARY,),
         ),
         interpret=interpret,
+        name="twinquant_dual_gemv_group",
     )(*operands)

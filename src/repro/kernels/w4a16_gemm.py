@@ -90,4 +90,5 @@ def w4a16_gemm(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY, pltpu.ARBITRARY),
         ),
         interpret=interpret,
+        name="w4a16_gemm",
     )(x, wp, ws)

@@ -57,6 +57,7 @@ Engine architecture (DESIGN.md §10, §14):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -604,6 +605,48 @@ _PREFIX_FAMILIES = ("dense", "moe", "mla_moe")
 # ---------------------------------------------------------------------------
 
 
+# Phases of an engine step (docs/serving.md "Observability"): each is a
+# profiler span ``engine.<phase>`` and the counters ``stats["<phase>_s"]``
+# (seconds) and ``stats["<phase>_n"]`` (calls).
+_PHASES = ("admit", "sample", "schedule", "launch", "device_wait", "download",
+           "commit", "upload")
+# The newest steps whose records ``engine.step_records`` keeps.
+_STEP_RECORDS = 4096
+
+
+@dataclasses.dataclass
+class StepRecord:
+    """One engine step as ``ContinuousBatchingEngine.step_records`` keeps it.
+
+    Times are ``time.monotonic()`` seconds, the clock of
+    ``Request.token_times``. ``spans`` lists each phase the step entered, in
+    order, as (phase, start, end); a phase opened inside another (a prefill
+    inside admission) lies inside its parent's interval. A ragged launch also
+    keeps the host arrays it uploaded: ``slot`` and ``pos`` per row (pad
+    rows carry slot B), ``ctx`` (rows committed per slot before the launch)
+    and ``logit_idx`` (the row whose logits each slot gets back)."""
+
+    step: int
+    t0: float
+    t1: float = 0.0
+    spans: list = dataclasses.field(default_factory=list)
+    rows: int = 0  # launched rows that carry a token
+    logits_bytes: int = 0
+    jit_miss: bool = False  # a jitted step traced, compiled or loaded
+    slot: Optional[np.ndarray] = None
+    pos: Optional[np.ndarray] = None
+    ctx: Optional[np.ndarray] = None
+    logit_idx: Optional[np.ndarray] = None
+
+    @property
+    def phases(self) -> dict:
+        """Seconds per phase; a phase entered twice sums."""
+        out: dict = {}
+        for name, a, b in self.spans:
+            out[name] = out.get(name, 0.0) + b - a
+        return out
+
+
 class ContinuousBatchingEngine:
     """Continuous-batching server: a static batch of B independent slot
     timelines, per-slot admission/eviction, per-request sampling, lock-step
@@ -726,7 +769,6 @@ class ContinuousBatchingEngine:
         self.ragged = False
         self.token_budget = int(token_budget)
         self.max_chunk_share = float(max_chunk_share)
-        self._ragged_traces: dict[int, int] = {}
         if ragged:
             ok = (
                 self.allocator is not None
@@ -748,7 +790,9 @@ class ContinuousBatchingEngine:
                         f"gets a row each step"
                     )
                 self.ragged = True
-                self._ragged = jax.jit(make_ragged_step(cfg))
+                # ``_ragged`` is what the step calls (a caller may wrap it);
+                # ``_ragged_jit`` is the jit whose cache counts misses
+                self._ragged = self._ragged_jit = jax.jit(make_ragged_step(cfg))
                 # host mirror of per-slot committed rows: the ragged loop
                 # never downloads state["pos"] (no per-step sync for it)
                 self._pos_host = np.zeros(batch_slots, np.int32)
@@ -800,7 +844,13 @@ class ContinuousBatchingEngine:
             "prefix_lookups": 0, "prefix_hits": 0, "prefix_hit_tokens": 0,
             "spec_launches": 0, "spec_slot_steps": 0,
             "spec_drafted": 0, "spec_accepted": 0,
+            # step spans and counters (docs/serving.md "Observability")
+            "step_s": 0.0, "step_n": 0, "logits_bytes": 0, "jit_misses": 0,
+            **{f"{p}_{u}": 0.0 if u == "s" else 0 for p in _PHASES for u in "sn"},
         }
+        self.step_records: deque[StepRecord] = deque(maxlen=_STEP_RECORDS)
+        self._record: Optional[StepRecord] = None  # the step in progress
+        self._lap: Optional[float] = None  # where the step's next phase starts
         # dispatch-counter baseline: routing() reports the delta, i.e. the
         # kernel routes this engine's traces took (quantized params only)
         from repro.kernels.dispatch import dispatch_counters
@@ -900,8 +950,11 @@ class ContinuousBatchingEngine:
         key = (bucket, off, tuple(sorted(req.frontend)))
         self._prefill_traces[key] = self._prefill_traces.get(key, 0) + 1
         t0 = time.monotonic()
-        logits, sub = self._prefill(self.params, jnp.asarray(toks), self._sub_template, **kwargs)
-        last = np.asarray(logits[0, -1].astype(jnp.float32))  # sync-point
+        with self._launch(self._prefill):
+            logits, sub = self._prefill(self.params, jnp.asarray(toks), self._sub_template,
+                                        **kwargs)
+            logits = logits[0, -1].astype(jnp.float32)
+        last = self._fetch_logits(logits)  # sync-point
         last = C.logits_tap(last, "prefill")
         self.stats["prefill_s"] += time.monotonic() - t0
         self.stats["prefill_tokens"] += s_real
@@ -1325,8 +1378,7 @@ class ContinuousBatchingEngine:
         stale rows invisible behind the prefix mask until overwritten."""
         k = self.spec_k
         tok = np.zeros((self.batch, k), np.int32)
-        with jax.transfer_guard("allow"):
-            pos = np.asarray(self.state["pos"])  # sync-point: next write offset per slot
+        pos = self._fetch(self.state["pos"])  # sync-point: next write offset per slot
         live: list[int] = []
         drafts: dict[int, list] = {}
         for i in active:
@@ -1345,9 +1397,10 @@ class ContinuousBatchingEngine:
         if not live:
             return
         t0 = time.monotonic()
-        with jax.transfer_guard("allow"):
+        with self._launch(self._decode), jax.transfer_guard("allow"):
             logits, self.state = self._decode(self.params, self.state, jnp.asarray(tok))
-            last = np.asarray(logits.astype(jnp.float32))  # sync-point: (B, k, V) verify download
+            logits = logits.astype(jnp.float32)
+        last = self._fetch_logits(logits)  # sync-point: (B, k, V) verify download
         last = C.logits_tap(last, "decode")
         dt = time.monotonic() - t0
         self._spec_traces[(self.batch, k)] = self._spec_traces.get((self.batch, k), 0) + 1
@@ -1372,7 +1425,7 @@ class ContinuousBatchingEngine:
                 self.stats["spec_accepted"] += n_acc
             committed[i] = 1 + n_acc
             delta[i] = k - committed[i]
-        with jax.transfer_guard("allow"):
+        with self._phase("upload"), jax.transfer_guard("allow"):
             # sync-point: upload the per-slot rewind (rejected rows become
             # invisible garbage past pos, overwritten by the next commits)
             self.state["pos"] = self.state["pos"] - jnp.asarray(delta)
@@ -1408,143 +1461,149 @@ class ContinuousBatchingEngine:
         displaced by admission), fill the remaining token budget with prompt
         chunks FIFO across admitting slots, then run ONE ``ragged_step``
         launch over the flat batch. Pad rows carry the sentinel slot id B and
-        are inert in attention and cache writes."""
-        self._steps += 1
-        self._expire_deadlines()
-        self._admit()
-        self._queue_depths.append(len(self.queue))
-        active = [i for i, s in enumerate(self.slots) if s is not None]
+        are inert in attention and cache writes. The phases admit, sample,
+        schedule, launch, device_wait, download, commit and admit again
+        partition the step."""
+        with self._phase("admit"):
+            self._expire_deadlines()
+            self._admit()
+            self._queue_depths.append(len(self.queue))
+            active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return 0
-        budget = self.token_budget
-        tokens = np.zeros(budget, np.int32)
-        slot = np.full(budget, self.batch, np.int32)  # pad sentinel = B
-        pos = np.zeros(budget, np.int32)
-        logit_idx = np.zeros(self.batch, np.int32)
-        row = 0
-        decode_rows: list[int] = []
         # decode tokens first: a slot mid-generation gets its row every step
-        for i in active:
-            req = self.slots[i]
-            if req._last_logits is None:
-                continue  # still prefilling — chunks scheduled below
-            nxt = self._sample(req)
-            self._emit_token(req, nxt)
-            # quota filled (or no cache row left for the new token): evict
-            # BEFORE the launch — its next logits would be discarded anyway
-            if len(req.out) >= req.max_new:
-                self._evict(i, req, truncated=False)
-            elif int(self._pos_host[i]) >= self.max_len:
-                self._evict(i, req, truncated=True)
-            else:
-                tokens[row] = nxt
-                slot[row] = i
-                pos[row] = self._pos_host[i]
-                logit_idx[i] = row
-                decode_rows.append(i)
-                row += 1
-        # prompt chunks fill whatever budget decode left, FIFO across slots,
-        # additionally capped at max_chunk_share of the token budget — the
-        # decode-priority knob: a long-prompt flood can never swell the
-        # launch beyond the configured share, so steady decoders keep their
-        # per-step cadence at a bounded launch size. The floor of one token
-        # keeps admission live even at tiny shares.
-        chunk_cap = max(1, int(self.token_budget * self.max_chunk_share))
-        chunks: list[tuple[int, int]] = []  # (slot, tokens scheduled)
-        n_chunk = 0
-        for i in active:
-            req = self.slots[i]
-            if req is None or req._last_logits is not None:
-                continue
-            space = min(budget - row, chunk_cap - n_chunk)
-            if space <= 0:
-                break
-            take = min(space, len(req._prompt) - req._filled)
-            tokens[row : row + take] = req._prompt[req._filled : req._filled + take]
-            slot[row : row + take] = i
-            pos[row : row + take] = self._pos_host[i] + np.arange(take, dtype=np.int32)
-            if req._filled + take == len(req._prompt):
-                logit_idx[i] = row + take - 1  # last prompt token's logits
-            chunks.append((i, take))
-            n_chunk += take
-            row += take
+        decode: list[tuple[int, int]] = []  # (slot, sampled token)
+        with self._phase("sample"):
+            for i in active:
+                req = self.slots[i]
+                if req._last_logits is None:
+                    continue  # still prefilling — chunks scheduled below
+                nxt = self._sample(req)
+                self._emit_token(req, nxt)
+                # quota filled (or no cache row left for the new token): evict
+                # BEFORE the launch — its next logits would be discarded anyway
+                if len(req.out) >= req.max_new:
+                    self._evict(i, req, truncated=False)
+                elif int(self._pos_host[i]) >= self.max_len:
+                    self._evict(i, req, truncated=True)
+                else:
+                    decode.append((i, nxt))
+        with self._phase("schedule"):
+            budget = self.token_budget
+            tokens = np.zeros(budget, np.int32)
+            slot = np.full(budget, self.batch, np.int32)  # pad sentinel = B
+            pos = np.zeros(budget, np.int32)
+            logit_idx = np.zeros(self.batch, np.int32)
+            for r, (i, nxt) in enumerate(decode):
+                tokens[r] = nxt
+                slot[r] = i
+                pos[r] = self._pos_host[i]
+                logit_idx[i] = r
+            row = len(decode)
+            # prompt chunks fill whatever budget decode left, FIFO across
+            # slots, additionally capped at max_chunk_share of the token
+            # budget — the decode-priority knob: a long-prompt flood can never
+            # swell the launch beyond the configured share, so steady decoders
+            # keep their per-step cadence at a bounded launch size. The floor
+            # of one token keeps admission live even at tiny shares.
+            chunk_cap = max(1, int(self.token_budget * self.max_chunk_share))
+            chunks: list[tuple[int, int]] = []  # (slot, tokens scheduled)
+            n_chunk = 0
+            for i in active:
+                req = self.slots[i]
+                if req is None or req._last_logits is not None:
+                    continue
+                space = min(budget - row, chunk_cap - n_chunk)
+                if space <= 0:
+                    break
+                take = min(space, len(req._prompt) - req._filled)
+                tokens[row : row + take] = req._prompt[req._filled : req._filled + take]
+                slot[row : row + take] = i
+                pos[row : row + take] = self._pos_host[i] + np.arange(take, dtype=np.int32)
+                if req._filled + take == len(req._prompt):
+                    logit_idx[i] = row + take - 1  # last prompt token's logits
+                chunks.append((i, take))
+                n_chunk += take
+                row += take
+            ctx = self._pos_host.copy()
         if row == 0:
-            self._admit()
+            with self._phase("admit"):
+                self._admit()
             return len(active)
         t0 = time.monotonic()
-        with jax.transfer_guard("allow"):
+        with self._launch(self._ragged_jit), jax.transfer_guard("allow"):
             logits, self.state = self._ragged(
                 self.params, self.state, jnp.asarray(tokens), jnp.asarray(slot),
-                jnp.asarray(pos), jnp.asarray(self._pos_host.copy()),
-                jnp.asarray(logit_idx),
+                jnp.asarray(pos), jnp.asarray(ctx), jnp.asarray(logit_idx),
             )
-            last = np.asarray(logits.astype(jnp.float32))  # sync-point: per-slot logits download
-        last = C.logits_tap(last, "ragged")
+            logits = logits.astype(jnp.float32)
+        last = self._fetch_logits(logits)  # sync-point: per-slot logits download
         dt = time.monotonic() - t0
-        # split wall time by scheduled-token share so both tok/s stay honest
-        self.stats["decode_s"] += dt * len(decode_rows) / row
-        self.stats["prefill_s"] += dt * n_chunk / row
-        self.stats["decode_steps"] += 1
-        self.stats["decode_tokens"] += len(decode_rows)
-        self.stats["prefill_tokens"] += n_chunk
-        self._ragged_traces[budget] = self._ragged_traces.get(budget, 0) + 1
-        # finite-logits guard at the step's single sync point: a NaN/Inf row
-        # fails only its own slot; every other slot's bytes are untouched
-        bad = set(C.nonfinite_rows(last, self.cfg.vocab))
-        for i in decode_rows:
-            req = self.slots[i]
-            if i in bad:
-                self.slots[i] = None
-                self._release_slot(i)
-                self._finish(req, RequestState.FAILED, "nan_logits",
-                             f"non-finite decode logits at engine step {self._steps}")
-                continue
-            self._pos_host[i] += 1
-            req._last_logits = last[i]
-        for i, take in chunks:
-            req = self.slots[i]
-            self._pos_host[i] += take
-            req._filled += take
-            if req._filled == len(req._prompt):
+        with self._phase("commit"):
+            last = C.logits_tap(last, "ragged")
+            rec = self._record
+            rec.rows, rec.slot, rec.pos, rec.ctx, rec.logit_idx = row, slot, pos, ctx, logit_idx
+            # split wall time by scheduled-token share so both tok/s stay honest
+            self.stats["decode_s"] += dt * len(decode) / row
+            self.stats["prefill_s"] += dt * n_chunk / row
+            self.stats["decode_steps"] += 1
+            self.stats["decode_tokens"] += len(decode)
+            self.stats["prefill_tokens"] += n_chunk
+            # finite-logits guard at the step's single sync point: a NaN/Inf row
+            # fails only its own slot; every other slot's bytes are untouched
+            bad = set(C.nonfinite_rows(last, self.cfg.vocab))
+            for i, _ in decode:
+                req = self.slots[i]
                 if i in bad:
                     self.slots[i] = None
                     self._release_slot(i)
                     self._finish(req, RequestState.FAILED, "nan_logits",
-                                 f"non-finite prefill logits at engine step "
-                                 f"{self._steps}")
+                                 f"non-finite decode logits at engine step {self._steps}")
                     continue
+                self._pos_host[i] += 1
                 req._last_logits = last[i]
-                self._set_status(req, RequestState.DECODE)
-                # deferred prefix registration: the prompt's pages are only
-                # fully written once its last chunk lands
-                if self.prefix_cache is not None and not req.frontend:
-                    self.prefix_cache.register(
-                        req._prompt, [int(p) for p in self._bt[i] if p >= 0]
-                    )
-        self._admit()
+            for i, take in chunks:
+                req = self.slots[i]
+                self._pos_host[i] += take
+                req._filled += take
+                if req._filled == len(req._prompt):
+                    if i in bad:
+                        self.slots[i] = None
+                        self._release_slot(i)
+                        self._finish(req, RequestState.FAILED, "nan_logits",
+                                     f"non-finite prefill logits at engine step "
+                                     f"{self._steps}")
+                        continue
+                    req._last_logits = last[i]
+                    self._set_status(req, RequestState.DECODE)
+                    # deferred prefix registration: the prompt's pages are only
+                    # fully written once its last chunk lands
+                    if self.prefix_cache is not None and not req.frontend:
+                        self.prefix_cache.register(
+                            req._prompt, [int(p) for p in self._bt[i] if p >= 0]
+                        )
+        with self._phase("admit"):
+            self._admit()
         return len(active)
 
-    def step(self) -> int:
-        """Admit queued work, sample one token per active slot, then one
-        lock-step decode for the slots that still need logits (ragged mode:
-        one unified chunked-prefill + decode launch, see ``_step_ragged``).
-        Returns the number of slots that were live at entry."""
-        if self.ragged:
-            return self._step_ragged()
-        self._steps += 1
-        self._expire_deadlines()
-        self._admit()
+    def _step_bucketed(self) -> int:
+        """One bucketed engine step: admission (prompts prefill as they are
+        admitted), then one sampled token per active slot and one lock-step
+        decode launch for the slots that still need logits."""
+        with self._phase("admit"):
+            self._expire_deadlines()
+            self._admit()
         self._queue_depths.append(len(self.queue))
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return 0
         if self.speculation:
             self._step_spec(active)
-            self._admit()
+            with self._phase("admit"):
+                self._admit()
             return len(active)
         tok = np.zeros((self.batch, 1), np.int32)
-        with jax.transfer_guard("allow"):
-            pos = np.asarray(self.state["pos"])  # sync-point: next write offset per slot
+        pos = self._fetch(self.state["pos"])  # sync-point: next write offset per slot
         live = []
         for i in active:
             req = self.slots[i]
@@ -1562,9 +1621,10 @@ class ContinuousBatchingEngine:
                 live.append(i)
         if live:
             t0 = time.monotonic()
-            with jax.transfer_guard("allow"):
+            with self._launch(self._decode), jax.transfer_guard("allow"):
                 logits, self.state = self._decode(self.params, self.state, jnp.asarray(tok))
-                last = np.asarray(logits[:, -1].astype(jnp.float32))  # sync-point
+                logits = logits[:, -1].astype(jnp.float32)
+            last = self._fetch_logits(logits)  # sync-point
             last = C.logits_tap(last, "decode")
             self.stats["decode_s"] += time.monotonic() - t0
             self.stats["decode_steps"] += 1
@@ -1582,8 +1642,88 @@ class ContinuousBatchingEngine:
                                  f"{self._steps}")
                 else:
                     req._last_logits = last[i]
-        self._admit()
+        with self._phase("admit"):
+            self._admit()
         return len(active)
+
+    def step(self) -> int:
+        """Admit queued work, sample one token per active slot, then one
+        lock-step decode for the slots that still need logits (ragged mode:
+        one unified chunked-prefill + decode launch, see ``_step_ragged``).
+        Returns the number of slots that were live at entry.
+
+        The step is a profiler step ``engine.step``; its seconds and calls
+        go to ``stats["step_s"]`` / ``stats["step_n"]`` and its
+        :class:`StepRecord` to ``step_records``."""
+        self._steps += 1
+        rec = self._record = StepRecord(self._steps, time.monotonic())
+        self._lap = rec.t0
+        try:
+            with jax.profiler.StepTraceAnnotation("engine.step", step_num=self._steps):
+                return self._step_ragged() if self.ragged else self._step_bucketed()
+        finally:
+            rec.t1 = time.monotonic()
+            self._record = self._lap = None
+            self.stats["step_s"] += rec.t1 - rec.t0
+            self.stats["step_n"] += 1
+            self.step_records.append(rec)
+
+    # -- step spans ---------------------------------------------------------
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Span one phase of the engine step: a profiler annotation
+        ``engine.<name>`` on the device trace's clock, its seconds added to
+        ``stats["<name>_s"]``, one call to ``stats["<name>_n"]``, and an
+        entry in the step's record. A phase at the top of a step starts
+        where the step's previous phase ended, so those phases partition the
+        step; a phase inside another, or outside any step, times itself."""
+        top = self._lap is not None
+        t0 = self._lap if top else time.monotonic()
+        self._lap = None
+        try:
+            with jax.profiler.TraceAnnotation(f"engine.{name}"):
+                yield
+        finally:
+            t1 = time.monotonic()
+            if top:
+                self._lap = t1
+            self.stats[f"{name}_s"] += t1 - t0
+            self.stats[f"{name}_n"] += 1
+            if self._record is not None:
+                self._record.spans.append((name, t0, t1))
+
+    @contextlib.contextmanager
+    def _launch(self, jitted):
+        """The ``engine.launch`` phase around one call of ``jitted``: uploads,
+        dispatch and return. A call after which the jit's executable cache
+        grew (a trace, a compile, or a load from the persistent cache)
+        counts in ``stats["jit_misses"]`` and marks the step's record."""
+        n0 = jitted._cache_size()
+        with self._phase("launch"):
+            yield
+            if jitted._cache_size() > n0:
+                self.stats["jit_misses"] += 1
+                if self._record is not None:
+                    self._record.jit_miss = True
+
+    def _fetch(self, x: jax.Array) -> np.ndarray:
+        """Bring ``x`` to the host at a sync point: ``engine.device_wait``
+        until the device has computed it, then ``engine.download`` for the
+        device-to-host copy alone."""
+        with self._phase("device_wait"):
+            x.block_until_ready()  # sync-point
+        with self._phase("download"), jax.transfer_guard("allow"):
+            return np.asarray(x)  # sync-point
+
+    def _fetch_logits(self, logits: jax.Array) -> np.ndarray:
+        """``_fetch`` for logits: their bytes go to ``stats["logits_bytes"]``
+        and to the step's record."""
+        last = self._fetch(logits)
+        self.stats["logits_bytes"] += last.nbytes
+        if self._record is not None:
+            self._record.logits_bytes += last.nbytes
+        return last
 
     # -- drivers ------------------------------------------------------------
 
@@ -1676,8 +1816,9 @@ class ContinuousBatchingEngine:
         dispatch-routing baseline is NOT reset: routing decisions happen
         at trace time, so a warm executable would otherwise report an empty
         route table. The prefill-trace inventory (compile_stats) persists for
-        the same reason."""
+        the same reason. ``step_records`` is emptied with the counters."""
         self.stats = {k: type(v)() for k, v in self.stats.items()}
+        self.step_records.clear()
         self._requests.clear()
         self._queue_depths.clear()
         if self.allocator is not None:
@@ -1700,8 +1841,9 @@ class ContinuousBatchingEngine:
                                    and not self.speculation) else 0,
             # ragged mode compiles ONE token-budget-shaped executable for
             # everything (chunked prefill + decode); the compile-budget
-            # sanitizer asserts ragged_traces + prefill_traces <= 2
-            "ragged_traces": len(self._ragged_traces),
+            # sanitizer asserts ragged_traces + prefill_traces <= 2. The
+            # jit's cache grows on every miss, so a recompile shows here.
+            "ragged_traces": self._ragged_jit._cache_size() if self.ragged else 0,
             # speculative mode likewise compiles ONE (batch, spec_k)-shaped
             # decode executable; every distinct spec launch shape is a trace
             "spec_traces": len(self._spec_traces),
