@@ -473,11 +473,16 @@ def validate_ragged_attention(
     """Contract for the ragged-attention launch (one mixed prefill/decode
     token batch of T rows attending paged KV pools through block tables).
 
-    The schedule pins the whole (T, H*hd) query panel, the (T, KV*hd)
-    in-batch K/V rows, the f32 online-softmax state, and the output in VMEM
-    while streaming one (page, KV*hd) K/V page pair per grid step — so T
-    (the engine token budget) is the knob that blows the budget, not the
-    sequence length."""
+    The schedule is a (B, maxp) grid whose step (b, j) computes only the
+    query tiles that cover slot b's rows, and only while page j holds
+    committed keys of slot b. Scalar-prefetched (SMEM): the block tables
+    and each slot's first row, last row and committed length. Pinned in
+    VMEM: the whole query panel, the in-batch K/V rows, the row metadata,
+    the f32 online-softmax state and the output, all row-tiled. Streamed:
+    one (page, KV*hd) K/V page pair per grid step, clamped to the slot's
+    last live page so the steps past it fetch nothing. So T (the engine
+    token budget, padded to whole tiles by the caller) is the knob that
+    blows the budget, not the sequence length."""
     positive(t, "T (token batch rows)", kind=kind)
     positive(page, "page_size", kind=kind)
     positive(maxp, "max_pages (block-table width)", kind=kind)
@@ -490,7 +495,7 @@ def validate_ragged_attention(
         ("v_page", (1, page, kvh * hd), jnp.bfloat16, "streamed"),
         ("k_tok", (t, kvh * hd), jnp.bfloat16, "pinned"),
         ("v_tok", (t, kvh * hd), jnp.bfloat16, "pinned"),
-        ("meta", (5 * t,), jnp.int32, "pinned"),
+        ("meta", (4 * t,), jnp.int32, "pinned"),
         ("out", (t, h * hd), jnp.bfloat16, "out"),
         ("m_s", (t, h), jnp.float32, "scratch"),
         ("l_s", (t, h), jnp.float32, "scratch"),

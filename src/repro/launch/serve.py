@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ModelConfig
+from repro.kernels.ragged_attention import ragged_work
 from repro.models import common as C
 from repro.models.registry import get_model
 
@@ -624,7 +625,9 @@ class StepRecord:
     inside admission) lies inside its parent's interval. A ragged launch also
     keeps the host arrays it uploaded: ``slot`` and ``pos`` per row (pad
     rows carry slot B), ``ctx`` (rows committed per slot before the launch)
-    and ``logit_idx`` (the row whose logits each slot gets back)."""
+    and ``logit_idx`` (the row whose logits each slot gets back), and what
+    its ragged attention computes in each layer (``ragged_work``): the
+    tile-rounded row spans and the live page steps."""
 
     step: int
     t0: float
@@ -637,6 +640,8 @@ class StepRecord:
     pos: Optional[np.ndarray] = None
     ctx: Optional[np.ndarray] = None
     logit_idx: Optional[np.ndarray] = None
+    attn_tile_rows: int = 0
+    attn_live_page_steps: int = 0
 
     @property
     def phases(self) -> dict:
@@ -846,6 +851,7 @@ class ContinuousBatchingEngine:
             "spec_drafted": 0, "spec_accepted": 0,
             # step spans and counters (docs/serving.md "Observability")
             "step_s": 0.0, "step_n": 0, "logits_bytes": 0, "jit_misses": 0,
+            "attn_tile_rows": 0, "attn_live_page_steps": 0,
             **{f"{p}_{u}": 0.0 if u == "s" else 0 for p in _PHASES for u in "sn"},
         }
         self.step_records: deque[StepRecord] = deque(maxlen=_STEP_RECORDS)
@@ -1526,6 +1532,9 @@ class ContinuousBatchingEngine:
                 n_chunk += take
                 row += take
             ctx = self._pos_host.copy()
+            tile_rows, page_steps = ragged_work(
+                slot, ctx, n_slots=self.batch, page=self.page_size
+            )
         if row == 0:
             with self._phase("admit"):
                 self._admit()
@@ -1543,6 +1552,9 @@ class ContinuousBatchingEngine:
             last = C.logits_tap(last, "ragged")
             rec = self._record
             rec.rows, rec.slot, rec.pos, rec.ctx, rec.logit_idx = row, slot, pos, ctx, logit_idx
+            rec.attn_tile_rows, rec.attn_live_page_steps = tile_rows, page_steps
+            self.stats["attn_tile_rows"] += tile_rows
+            self.stats["attn_live_page_steps"] += page_steps
             # split wall time by scheduled-token share so both tok/s stay honest
             self.stats["decode_s"] += dt * len(decode) / row
             self.stats["prefill_s"] += dt * n_chunk / row
