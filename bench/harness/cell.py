@@ -1,16 +1,17 @@
 """One cell, end to end: set-up, window, late first tokens, metrics, check.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration file, its traffic file, and one reader per per-layer
-metric in ``bench/layer_metrics/<metric>.py``. Adding a configuration, a mix
-or a metric adds files and entries; nothing here changes.
+its configuration file, the architecture that file names
+(``bench/architectures/<name>.py``), its traffic file, and one reader per
+metric (``bench/end_to_end/<metric>.py``, ``bench/layer_metrics/<metric>.py``).
+Adding an architecture, a configuration, a mix or a metric adds files and
+entries; nothing here changes.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import shutil
 import tempfile
@@ -18,9 +19,8 @@ import time
 from pathlib import Path
 from typing import Optional
 
-import numpy as np
-
 from harness import check as checkmod
+from harness import load as loadmod
 from harness.driver import Driver
 from harness.traffic import make_generator
 
@@ -34,6 +34,7 @@ class Spec:
     mix: dict
     end_to_end: list
     per_layer: list
+    architecture: object  # the module bench/architectures/<conf["architecture"]>.py
 
 
 def _bench_dir(root: Path) -> Path:
@@ -47,7 +48,12 @@ def load(root: Path, workload: str) -> Spec:
         raise KeyError(f"unknown workload {workload!r}; known: {sorted(cells)}")
     cell = cells[workload]
     confs = {c["name"]: c for c in bench["configs"]}
-    conf = json.loads((root / confs[cell["config"]]["file"]).read_text())
+    conf_file = confs[cell["config"]]["file"]
+    conf = json.loads((root / conf_file).read_text())
+    if "architecture" not in conf:
+        raise ValueError(f'configuration {conf_file} has no "architecture" key: it names '
+                         f"the module bench/architectures/<architecture>.py")
+    architecture = loadmod.module(root, "architectures", conf["architecture"])
     mix = json.loads((_bench_dir(root) / "traffic" / f"{cell['traffic']}.json").read_text())
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or workload in m["workloads"]]
@@ -55,18 +61,13 @@ def load(root: Path, workload: str) -> Spec:
     pl = [m for m in bench["per_layer"]
           if workload in m.get("workloads", ())
           or ("workloads" not in m and m["moves"] in names)]
-    return Spec(root, workload, cell, conf, mix, e2e, pl)
+    return Spec(root, workload, cell, conf, mix, e2e, pl, architecture)
 
 
 def reader(root: Path, kind: str, metric: str):
     """The ``read(ctx)`` function of ``bench/<kind>/<metric>.py``, where
     kind is ``end_to_end`` or ``layer_metrics``."""
-    path = _bench_dir(root) / kind / f"{metric}.py"
-    mod_name = f"bench_{kind}_{metric}".replace(".", "_").replace("-", "_")
-    mod_spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return loadmod.module(root, kind, metric).read
 
 
 class CompileClock:
@@ -102,37 +103,47 @@ def compile_clock() -> CompileClock:
     return _CLOCK
 
 
-class LaunchLog:
-    """Host copies of each ragged launch's row metadata (slot, pos, ctx,
-    logit rows), so the cost functions can count the rows that carry a
-    token. It wraps the engine's jitted step in every run, traced or not, so
-    both kinds of run drive the same path; it records only while ``on``.
-    The program has no counter for this yet (PERF.md, Open questions)."""
+class GcClock:
+    """Each of Python's garbage collections: (end time, generation, seconds)."""
 
-    def __init__(self, engine):
-        self.engine = engine
-        self.fn = engine._ragged
-        self.records: list = []
-        self.on = False
+    def __init__(self):
+        self.events: list = []
+        self._t0 = 0.0
+        gc.callbacks.append(self)
 
-    def __call__(self, params, state, tokens, slot, pos, ctx, logit_idx):
-        out = self.fn(params, state, tokens, slot, pos, ctx, logit_idx)
-        if self.on:
-            self.records.append((time.monotonic(), slot, pos, ctx, logit_idx))
-        return out
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.monotonic()
+        else:
+            self.events.append((time.monotonic(), info["generation"],
+                                time.monotonic() - self._t0))
 
-    def install(self) -> "LaunchLog":
-        self.engine._ragged = self
-        return self
-
-    def host(self) -> list:
-        return [(t, *(np.asarray(a) for a in arrays)) for t, *arrays in self.records]
+    def report(self, t0: float, t1: float) -> str:
+        ev = [(g, d) for t, g, d in self.events if t0 <= t <= t1]
+        full = [d for g, d in ev if g == 2]
+        return (f"gc in the window: {len(ev)} collections, {len(full)} of generation 2"
+                f" (longest {1e3 * max(full, default=0.0):.1f} ms), "
+                f"{1e3 * sum(d for _, d in ev):.1f} ms in all")
 
 
-def pool_pages(cfg, eng: dict) -> tuple:
+def longest_step(records: list, t0: float, t1: float) -> str:
+    """The longest engine step in ``[t0, t1]`` and its longest phase: a stall
+    shows here as one step many times the others."""
+    recs = [r for r in records if t0 <= r.t0 and r.t1 <= t1]
+    if not recs:
+        return "no engine step in the window"
+    dur = sorted(r.t1 - r.t0 for r in recs)
+    r = max(recs, key=lambda r: r.t1 - r.t0)
+    name, sec = max(r.phases.items(), key=lambda kv: kv[1], default=("none", 0.0))
+    return (f"engine steps in the window: median {1e3 * dur[len(dur) // 2]:.2f} ms, "
+            f"longest {1e3 * (r.t1 - r.t0):.2f} ms ({name} {1e3 * sec:.2f} ms, "
+            f"{r.t0 - t0:.1f} s into the window), {sum(d > 0.25 for d in dur)} over 250 ms")
+
+
+def pool_pages(kv_bytes_per_token: int, eng: dict) -> tuple:
     """Pages the pool gets: every slot's full timeline, so the pool never
     bounds admission in these cells. Returns (n_pages, page_bytes)."""
-    page_bytes = cfg.n_layers * eng["page_size"] * cfg.n_kv_heads * cfg.head_dim * 2 * 2
+    page_bytes = kv_bytes_per_token * eng["page_size"]
     return eng["batch_slots"] * -(-eng["max_len"] // eng["page_size"]), page_bytes
 
 
@@ -148,15 +159,17 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
 
     log: list = []
     clock = compile_clock()
+    gc_clock = GcClock()
     mix, eng_conf = spec.mix, spec.mix["engine"]
+    A = spec.architecture
     cfg = program.model_config(spec.conf)
-    arch = program.arch_of(cfg)
+    arch = A.arch_of(cfg)
 
     t0 = time.monotonic()
-    params = program.fuse(program.build_params(cfg, seed), dev)
+    params = program.fuse(A.build_params(arch, seed), dev)
     jax.block_until_ready(params)
     log.append(f"weights {time.monotonic() - t0:.3f} s")
-    n_pages, page_bytes = pool_pages(cfg, eng_conf)
+    n_pages, page_bytes = pool_pages(A.kv_bytes_per_token(arch), eng_conf)
     engine = program.build_engine(cfg, params, mix, n_pages)
     del params
     ms = dev.memory_stats() or {}
@@ -172,7 +185,6 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
     if hasattr(gen, "horizon"):
         gen.horizon = warm_s + seconds + drain_s + warm_cap_s
     drv = Driver(engine, gen, annotate=trace)
-    launches = LaunchLog(engine).install()
 
     # set-up: the traffic's warm period, then on until a request has
     # finished (its release compiles the engine's exit-path updates) and, for
@@ -198,13 +210,11 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
     mark0 = drv.mark()
     if trace:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
-        launches.on = True
         jax.profiler.start_trace(trace_dir)
         drv.run_until(w0 + min(trace_s, seconds))
         jax.block_until_ready(engine.state)
         mark1 = drv.mark()
         jax.profiler.stop_trace()
-        launches.on = False
     w1 = w0 + seconds
     drv.run_until(w1)
     w1 = max(w1, time.monotonic())
@@ -215,6 +225,9 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
     d = {k: mark1[1][k] - mark0[1][k] for k in ("decode_steps", "decode_tokens", "prefill_tokens")}
     log.append(f"window: {d['decode_steps']} launches, {d['decode_tokens']} decode rows, "
                f"{d['prefill_tokens']} prefill rows")
+    log.append(longest_step(engine.step_records, w0, w1))
+    log.append(gc_clock.report(w0, w1))
+    gc.callbacks.remove(gc_clock)
     window_compiles = clock.between(w0, w1)
     if window_compiles:
         log.append(f"compiled in the window: {clock.names(w0, w1)}")
@@ -243,13 +256,13 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = Context(spec=spec, arch=arch, driver=drv, device_kind=dev.device_kind,
                       t0=mark0[0], t1=mark1[0], stats0=mark0[1], stats1=mark1[1],
-                      launches=launches.host(), trace=tr)
+                      architecture=A, trace=tr)
         wanted, kind = spec.per_layer, "layer_metrics"
         result["breakdown"] = tr.breakdown()
     else:
         ctx = Context(spec=spec, arch=arch, driver=drv, device_kind=dev.device_kind,
                       t0=w0, t1=w1, stats0=mark0[1], stats1=mark1[1],
-                      censor=censor, setup_s=setup_s)
+                      architecture=A, censor=censor, setup_s=setup_s)
         wanted, kind = spec.end_to_end, "end_to_end"
     for m in wanted:
         v = reader(spec.root, kind, m["name"])(ctx)
@@ -264,10 +277,11 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, dev, *, t_start: flo
         result["device"]["window_s"] = tr.window_s
 
     sample = checkmod.sample(drv.sent, seed, mix.get("check", {}))
-    del engine, drv.engine, launches
+    del engine, drv.engine
     gc.collect()
     t_ref = time.monotonic()
-    rd = checkmod.readings(spec.conf, arch, seed, sample, control) if sample else {}
+    ref = loadmod.module(spec.root, "references", spec.conf["reference"])
+    rd = checkmod.readings(ref, arch, seed, sample, control) if sample else {}
     judged = checkmod.compare(spec.conf, sample, rd, control)
     compared = judged["program"]
     log.append(f"reference over {len(sample)} requests "

@@ -16,20 +16,7 @@ limit is where it is.
 
 from __future__ import annotations
 
-import importlib.util
-from pathlib import Path
-
 import numpy as np
-
-REFERENCES = Path(__file__).resolve().parents[1] / "references"
-
-
-def reference(name: str):
-    path = REFERENCES / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def sample(sent: list, seed: int, conf: dict) -> list:
@@ -69,10 +56,10 @@ def gaps(ref_logits: list, chosen: list) -> np.ndarray:
     return np.concatenate(out) if out else np.zeros(0)
 
 
-def readings(conf: dict, arch, seed: int, picked: list, control: bool = False) -> dict:
+def readings(ref, arch, seed: int, picked: list, control: bool = False) -> dict:
     """{"program": gaps of the served tokens, "control": gaps of the tokens
-    the lower-precision reference puts first (with ``control``)}."""
-    ref = reference(conf["reference"])
+    the lower-precision reference puts first (with ``control``)}; ``ref`` is
+    the configuration's reference module."""
     seqs, rows, served = sequences(picked)
     stores = ("bfloat16",) + (("float8_e4m3fn",) if control else ())
     lg = ref.logits_at(seed, arch, seqs, rows, stores)

@@ -69,5 +69,6 @@ def ragged_attention(keys_per_row: Sequence[int], ctx_per_slot: Sequence[int],
 
 
 def lm_head(rows: int, d_model: int, vocab: int) -> Cost:
-    """Tied-embedding logits for ``rows`` rows (bf16): the table once."""
+    """Logits of ``rows`` rows over a bf16 (vocab, d_model) table, tied to
+    the embedding or not: the table once, f32 logits out."""
     return Cost(2.0 * rows * d_model * vocab, 2.0 * vocab * d_model + 4.0 * rows * vocab)

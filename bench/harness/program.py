@@ -1,12 +1,13 @@
 """Everything the benchmark takes from the program, in one place: the model
-configuration, the packed W4A4 tree built from the seeded model, and the
-paged ragged serving engine.
+configuration, the packer of one W4A4 linear, and the paged ragged serving
+engine.
 
-The weights are drawn on the device by ``harness/model.py`` and packed with
-the program's own ``pack_twinquant_weights`` into the leaves that
-``quantize_params`` emits, layer by layer inside one jitted call, so the
-float linears of the full model never exist at once. The engine's own
-``fuse_params`` then merges sibling packs as in serving.
+An architecture (``bench/architectures/<name>.py``) draws its weights on the
+device and packs each linear with ``pack_linear`` (the program's own
+``pack_twinquant_weights``) into the leaves that ``quantize_params`` emits,
+layer by layer inside one jitted call, so the float linears of the full
+model never exist at once. The engine's own ``fuse_params`` then merges
+sibling packs as in serving.
 """
 
 from __future__ import annotations
@@ -27,55 +28,20 @@ def model_config(conf: dict):
     return get_config(conf["registered"], **conf.get("overrides", {})).replace(quant=spec)
 
 
-def arch_of(cfg) -> M.Arch:
-    return M.Arch(
-        n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim, d_ff=cfg.d_ff,
-        vocab=cfg.vocab, padded_vocab=cfg.padded_vocab, qkv_bias=cfg.qkv_bias,
-        rope_theta=cfg.rope_theta, rope_fraction=cfg.rope_fraction,
-        norm_eps=cfg.norm_eps, rank=cfg.quant.rank, group=cfg.quant.group_size,
-        a_bits=cfg.quant.a_bits,
-    )
-
-
-def _pack_linear(lin: dict, arch: M.Arch) -> dict:
+def pack_linear(lin: dict, a_bits: int, group: int) -> dict:
+    """One drawn linear (``model.draw_linear``) as the program's W4A4 pack."""
     from repro.kernels.ref import pack_twinquant_weights
 
     k = lin["r"].shape[0]
     tq = pack_twinquant_weights(
         M.dequant(lin["u"], lin["us"]), M.dequant(lin["v"], lin["vs"]),
-        M.dequant(lin["r"], lin["rs"]), a_bits=arch.a_bits, group=min(arch.group, k),
+        M.dequant(lin["r"], lin["rs"]), a_bits=a_bits, group=min(group, k),
     )
     out = {"up": tq.up, "us": tq.us, "vp": tq.vp, "vs": tq.vs, "rp": tq.rp,
-           "rs": tq.rs, "abits": jnp.zeros((arch.a_bits,), jnp.int8)}
+           "rs": tq.rs, "abits": jnp.zeros((a_bits,), jnp.int8)}
     if "b" in lin:
         out["b"] = lin["b"]
     return out
-
-
-def _packed_layer(lkey, arch: M.Arch, layer) -> dict:
-    w = M.draw_layer(lkey, arch, layer)
-    tree = {"attn": {}, "mlp": {}, "ln1": w["ln1"], "ln2": w["ln2"]}
-    for name, (parent, *_) in arch.linears().items():
-        tree[parent][name] = _pack_linear(w[name], arch)
-    return tree
-
-
-def build_params(cfg, seed: int) -> dict:
-    """The unfused packed W4A4 tree of ``cfg`` from ``seed``, on the default
-    device, in one jitted call (layers drawn and packed one at a time)."""
-    arch = arch_of(cfg)
-
-    @jax.jit
-    def build(key):
-        layers = jax.lax.map(
-            lambda i: _packed_layer(M.layer_key(key, i), arch, i),
-            jnp.arange(arch.n_layers),
-        )
-        return {"embed": M.draw_embed(key, arch), "layers": layers,
-                "ln_f": M.draw_final_norm(key, arch)}
-
-    return build(M.root_key(seed))
 
 
 def fuse(params, device):

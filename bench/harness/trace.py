@@ -9,7 +9,12 @@ end of the last one.
 - busy: the union of operation intervals inside the window, per device,
   averaged over the devices that ran anything.
 - kernel time: the summed durations of the operations a kernel pattern
-  matches (by operation name or any string the event carries).
+  matches (by operation name or any string the event carries) by class
+  (``KERNELS``), which also takes the fusions whose HLO names a kernel's
+  output; and the summed durations of each Pallas kernel alone (an
+  operation whose HLO is a ``tpu_custom_call``), by the name of its
+  ``pallas_call`` (the operation's name without its ``.N`` suffix). A reader
+  of a new kernel reads its name; no class is added.
 - idle gaps: the stretches of the window with no operation on the device,
   each put to the harness span that was open at its middle.
 """
@@ -27,6 +32,9 @@ KERNELS = {
     "dual_gemm": re.compile(r"dual_gemm|twinquant_dual_gemm"),
     "ragged_attention": re.compile(r"ragged_attention"),
 }
+# a Pallas kernel: an operation whose HLO is a custom call to the TPU's
+# kernel target (a fusion that only reads a kernel's output is none)
+PALLAS = re.compile(r'custom_call_target="tpu_custom_call"')
 HOST_SPAN = re.compile(r"^bench\.")
 OPS_LINE = "XLA Ops"
 # operations that only contain others (a scan's loop): busy, but not listed
@@ -39,22 +47,30 @@ def short_name(name: str) -> str:
     return name.split(" = ", 1)[0].strip().lstrip("%")
 
 
+def call_name(name: str) -> str:
+    """``twinquant_dual_gemm_group.29`` -> ``twinquant_dual_gemm_group``."""
+    return re.sub(r"(\.\d+)+$", "", name)
+
+
 @dataclasses.dataclass
 class Op:
     name: str
     start: float  # seconds, trace clock
     end: float
-    kernel: str = ""
+    kernel: str = ""  # the KERNELS class the operation falls in
+    pallas: bool = False  # a Pallas kernel, whether a class matches it or not
 
 
 @dataclasses.dataclass
 class Summary:
     window_s: float
     busy_s: float
-    kernel_s: dict
+    kernel_s: dict  # KERNELS class -> summed seconds
     op_s: dict  # operation name -> summed seconds
     idle_by_span: dict  # host span name -> idle seconds
     n_ops: int
+    # pallas_call name -> summed seconds of its kernel
+    kernel_by_name: dict = dataclasses.field(default_factory=dict)
 
     @property
     def idle_share(self) -> float:
@@ -72,6 +88,10 @@ def classify(name: str, strings: Iterable[str]) -> str:
         if pat.search(name) or any(pat.search(s) for s in strings):
             return kernel
     return ""
+
+
+def is_pallas(name: str, strings: Iterable[str]) -> bool:
+    return bool(PALLAS.search(name)) or any(PALLAS.search(s) for s in strings)
 
 
 def union(intervals: list) -> list:
@@ -95,6 +115,7 @@ def summarize(device_ops: dict, spans: list) -> Summary:
     window = w1 - w0
     busy_per_dev = []
     kernel_s: dict = defaultdict(float)
+    by_name: dict = defaultdict(float)
     op_s: dict = defaultdict(float)
     idle: dict = defaultdict(float)
     spans = sorted(spans, key=lambda s: s[1])
@@ -110,6 +131,8 @@ def summarize(device_ops: dict, spans: list) -> Summary:
                 op_s[f"{o.kernel}:{o.name}" if o.kernel else o.name] += d
             if o.kernel:
                 kernel_s[o.kernel] += d
+            if o.pallas:
+                by_name[call_name(o.name)] += d
         merged = union([(max(o.start, w0), min(o.end, w1)) for o in inside])
         busy_per_dev.append(sum(b - a for a, b in merged))
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
@@ -124,6 +147,7 @@ def summarize(device_ops: dict, spans: list) -> Summary:
         op_s={k: v / n_dev for k, v in op_s.items()},
         idle_by_span={k: v / n_dev for k, v in idle.items()},
         n_ops=n,
+        kernel_by_name={k: v / n_dev for k, v in by_name.items()},
     )
 
 
@@ -157,7 +181,7 @@ def reduce_trace(trace_dir: str) -> Summary:
                     strings = [str(v) for _, v in ev.stats if isinstance(v, str)]
                     ops.append(Op(short_name(ev.name), ev.start_ns * 1e-9,
                                   (ev.start_ns + ev.duration_ns) * 1e-9,
-                                  classify(ev.name, strings)))
+                                  classify(ev.name, strings), is_pallas(ev.name, strings)))
             device_ops[plane.name] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:

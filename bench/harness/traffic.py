@@ -3,9 +3,12 @@
 Every seed gets the same work in another order. Lengths, gaps between
 arrivals, think times, turn counts and system-prompt choices are drawn from a
 *deck*: the distribution's quantiles at ``(i + 0.5) / n``, shuffled by the
-seed. Token contents are drawn from the seed. Arrivals are clocked in
-seconds of the host's monotonic clock, never in engine steps, so a slower
-system sees the same offered load and its queue grows.
+seed and reshuffled each time it runs out. ``n`` is the mix's ``deck``
+(default 512). A window that serves a few whole decks serves the same
+lengths on every seed; the seed moves only the order and the few draws at
+the window's edges. Token contents are drawn from the seed. Arrivals are
+clocked in seconds of the host's monotonic clock, never in engine steps,
+so a slower system sees the same offered load and its queue grows.
 
 Loop kinds:
 
@@ -31,7 +34,7 @@ from typing import Optional
 
 import numpy as np
 
-DECK = 512  # quantiles in every deck
+DECK = 512  # quantiles in a deck, unless the mix sets "deck"
 
 
 def quantile_deck(dist: dict, n: int) -> np.ndarray:
@@ -96,7 +99,8 @@ class Generator:
         return self.rng.integers(0, self.vocab, int(n)).astype(np.int32)
 
     def _deck(self, dist: dict) -> Deck:
-        return Deck(dist, DECK, np.random.default_rng(self.rng.integers(1 << 62)))
+        return Deck(dist, int(self.mix.get("deck", DECK)),
+                    np.random.default_rng(self.rng.integers(1 << 62)))
 
     def push(self, item: Item) -> None:
         heapq.heappush(self._heap, (item.due, next(self._tie), item))

@@ -40,7 +40,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import model as M
+from harness import load, model as M
+
+A = load.sibling(__file__, "architectures", "dense_gqa")
 
 HI = jax.lax.Precision.HIGHEST  # every product at f32 accuracy, also on a TPU
 QBLOCK = 512  # query rows per attention block (bounds the score matrix)
@@ -79,8 +81,8 @@ def _int_gemm(xq, xs, w, ws):
     return acc
 
 
-def _draw_layer(lkey, arch: M.Arch, layer) -> dict:
-    w = M.draw_layer(lkey, arch, layer)
+def _draw_layer(lkey, arch: A.Arch, layer) -> dict:
+    w = A.draw_layer(lkey, arch, layer)
     out = {"ln1": w["ln1"].astype(jnp.float32), "ln2": w["ln2"].astype(jnp.float32)}
     for name in arch.linears():
         lin = dict(w[name])
@@ -90,7 +92,7 @@ def _draw_layer(lkey, arch: M.Arch, layer) -> dict:
     return out
 
 
-def _linear(p: dict, x, arch: M.Arch, rnd):
+def _linear(p: dict, x, arch: A.Arch, rnd):
     """TwinQuant W4A4: y = Xq R + requant(Xq U) V, all integer GEMMs with
     per-group scales; the output is stored, then the bias added."""
     k = x.shape[-1]
@@ -108,7 +110,7 @@ def _rmsnorm(x, g, eps, rnd):
     return rnd(rnd(n) * rnd(g))
 
 
-def _rope(x, pos, arch: M.Arch, rnd):
+def _rope(x, pos, arch: A.Arch, rnd):
     """x (S, H, hd), pos (S,): rotate the first ``rope_fraction`` of each
     head in interleaved pairs; cos/sin are stored like any bf16 value."""
     hd = x.shape[-1]
@@ -125,7 +127,7 @@ def _rope(x, pos, arch: M.Arch, rnd):
     return jnp.concatenate([yr, x[..., rot:]], axis=-1)
 
 
-def _attention(q, k, v, arch: M.Arch, rnd):
+def _attention(q, k, v, arch: A.Arch, rnd):
     """Causal GQA over a whole sequence, f32 softmax, query blocks."""
     s, h, hd = q.shape
     g = h // arch.n_kv_heads
@@ -158,7 +160,7 @@ STORES = {"bfloat16": (8, 7), "float8_e4m3fn": (4, 3)}
 
 
 @functools.partial(jax.jit, static_argnames=("arch", "store"))
-def _layer(w: dict, x, arch: M.Arch, store):
+def _layer(w: dict, x, arch: A.Arch, store):
     rnd = rounding(store)
 
     s = x.shape[0]
@@ -180,21 +182,22 @@ _draw = jax.jit(_draw_layer, static_argnames=("arch",))
 
 
 @functools.partial(jax.jit, static_argnames=("arch", "store"))
-def _logits(x, rows, gf, embed, arch: M.Arch, store):
+def _logits(x, rows, gf, table, arch: A.Arch, store):
+    """Logits over the real vocabulary at ``rows`` of the final states
+    ``x``; ``table`` is the (padded_vocab, D) lm head."""
     rnd = rounding(store)
 
     h = _rmsnorm(x[rows], gf.astype(jnp.float32), arch.norm_eps, rnd)
-    return jnp.matmul(h, rnd(embed[: arch.vocab].astype(jnp.float32)).T, precision=HI)
+    return jnp.matmul(h, rnd(table[: arch.vocab].astype(jnp.float32)).T, precision=HI)
 
 
-def logits_at(seed: int, arch: M.Arch, seqs: list, rows: list, stores: tuple) -> dict:
-    """Reference logits (f32, over the real vocabulary) of each sequence in
-    ``seqs`` (1-D int arrays) at the positions ``rows[i]``, for every
-    precision in ``stores`` (names in STORES): {store: [array (len(rows[i]),
-    vocab)]}.
+def final_states(seed: int, arch: A.Arch, seqs: list, stores: tuple) -> dict:
+    """The residual stream after the last layer of each sequence in ``seqs``
+    (1-D int arrays, padded to a multiple of ``BUCKET``), for every
+    precision in ``stores`` (names in STORES): {store: [array (S, D)]}.
     Weights are drawn once per layer and shared by all sequences."""
     key = M.root_key(seed)
-    embed = M.draw_embed(key, arch)
+    embed = A.draw_embed(key, arch)
     xs = {}
     for st in stores:
         xs[st] = []
@@ -210,11 +213,22 @@ def logits_at(seed: int, arch: M.Arch, seqs: list, rows: list, stores: tuple) ->
         for st in stores:
             xs[st] = [_layer(w, x, arch, st) for x in xs[st]]
         del w
-    gf = M.draw_final_norm(key, arch)
-    out = {}
-    for st in stores:
-        out[st] = [
-            np.asarray(_logits(x, jnp.asarray(r, jnp.int32), gf, embed, arch, st))
-            for x, r in zip(xs[st], rows)
-        ]
-    return out
+    return xs
+
+
+def head_logits(xs: dict, rows: list, gf, table, arch: A.Arch) -> dict:
+    """{store: [logits (len(rows[i]), vocab)]} of ``final_states``' output
+    through the final norm ``gf`` and the lm head ``table``."""
+    return {st: [np.asarray(_logits(x, jnp.asarray(r, jnp.int32), gf, table, arch, st))
+                 for x, r in zip(xs[st], rows)]
+            for st in xs}
+
+
+def logits_at(seed: int, arch: A.Arch, seqs: list, rows: list, stores: tuple) -> dict:
+    """Reference logits (f32, over the real vocabulary) of each sequence in
+    ``seqs`` (1-D int arrays) at the positions ``rows[i]``, for every
+    precision in ``stores`` (names in STORES): {store: [array (len(rows[i]),
+    vocab)]}. The lm head is the embedding (tied)."""
+    xs = final_states(seed, arch, seqs, stores)
+    key = M.root_key(seed)
+    return head_logits(xs, rows, A.draw_final_norm(key, arch), A.draw_embed(key, arch), arch)

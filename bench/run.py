@@ -5,7 +5,8 @@
 
 Run from the root of a checkout that holds ``BENCHMARK.json``, ``bench/``
 and the program under ``src/``. The cell names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
+(``bench/configs/<config>.json``, which names its architecture,
+``bench/architectures/<name>.py``) and a traffic mix
 (``bench/traffic/<mix>.json``); per-layer metrics are read by
 ``bench/layer_metrics/<metric>.py``. One process per run, on the machine's
 first chip. Without a TPU the run exits with code 3 and prints no result.

@@ -15,6 +15,14 @@ BENCH = REPO / "bench"
 if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
+
+def architecture(name: str):
+    """The module ``bench/architectures/<name>.py`` of this repository."""
+    from harness import load
+
+    return load.module(REPO, "architectures", name)
+
+
 # Widths are multiples of 256, so every linear is packed (W4A4) as at full
 # size; depth, heads and vocabulary are cut so a run takes seconds.
 TINY = {"n_layers": 2, "d_model": 256, "n_heads": 2, "n_kv_heads": 1,
@@ -75,14 +83,21 @@ PENDING = {
 
 
 def make_root(tmp: Path, cells: list, configs: dict, mixes: dict,
-              metrics: dict = None) -> Path:
+              metrics: dict = None, files: dict = None) -> Path:
     """A checkout at ``tmp``: the benchmark's files, a link to the program,
     and a BENCHMARK.json naming ``cells`` [(name, config, mix)], with the
     given configuration and traffic files added. ``metrics`` maps a new
-    per-layer metric name to its reader's source."""
+    per-layer metric name to its reader's source; ``files`` maps a path
+    under ``bench/`` to a file to copy there, which must be new."""
     root = tmp / "checkout"
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    for rel, src in (files or {}).items():
+        dst = root / "bench" / rel
+        if dst.exists():
+            raise FileExistsError(f"bench/{rel} is not a new file")
+        dst.parent.mkdir(parents=True, exist_ok=True)
+        shutil.copy(src, dst)
     (root / "src").symlink_to(REPO / "src")
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     for kind, pending in PENDING.items():

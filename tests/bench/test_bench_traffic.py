@@ -78,13 +78,23 @@ def test_other_distributions():
 @pytest.mark.parametrize("mix", MIXES)
 def test_every_seed_gets_the_same_work(mix):
     m = load(mix)
-    n = traffic.DECK
+    n = m.get("deck", traffic.DECK)
 
     def lengths(seed):
         deck = traffic.make_generator(m, seed, 1000)._deck(m["output"])
         return sorted(deck.draw() for _ in range(n))
 
     assert lengths(1) == lengths(2**35 + 9)
+
+
+def test_each_deck_of_draws_holds_every_quantile_once():
+    m = dict(load("batch_decode"), deck=16)
+    gen = traffic.make_generator(m, 2**33 + 5, 1000)
+    quantiles = sorted(traffic.quantile_deck(m["output"], 16).tolist())
+    draws = [gen.output.draw() for _ in range(3 * 16)]
+    for i in range(0, len(draws), 16):
+        assert sorted(draws[i:i + 16]) == quantiles
+    assert draws[:16] != draws[16:32]  # reshuffled each time it runs out
 
 
 def test_closed_loop_starts_stationary():
